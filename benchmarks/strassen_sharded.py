@@ -15,7 +15,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.core.strassen import strassen_matmul  # noqa: E402
-from repro.launch.hlo_cost import PEAK_FLOPS, parse_hlo_cost  # noqa: E402
+from repro.launch.hlo_cost import device_peaks, parse_hlo_cost  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 
 
@@ -37,7 +37,7 @@ def main() -> None:
             )
         cost = parse_hlo_cost(compiled.as_text())
         base = base or cost.flops
-        t_c = cost.flops / PEAK_FLOPS
+        t_c = cost.flops / device_peaks().flops
         print(
             f"strassen_sharded/depth{depth},0.0,"
             f"flops_per_dev={cost.flops:.4g};t_compute={t_c*1e3:.3f}ms;"

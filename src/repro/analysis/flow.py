@@ -10,7 +10,7 @@ from the paper's run-time-reconfigurable datapath are checked:
 ``FLOW-F64``
     No float64 value — invar, constvar, or equation output — may appear in
     a device path, except inside declared oracle sub-jaxprs.  Traces run
-    under ``jax.experimental.enable_x64`` so a latent f64 cannot hide
+    under ``jax.enable_x64`` so a latent f64 cannot hide
     behind jax's silent default-config downcast.  Weak-typed scalars
     (plain Python floats awaiting promotion) are exempt.
 
@@ -85,13 +85,13 @@ def analyze_flow(fn, *args, path: str,
     ``mode_args`` are positional indices (into ``args``) of mode-select
     scalars; ``oracles`` are substrings of nested-jaxpr names whose bodies
     are declared f64-capable (reference oracles) and skipped; ``x64``
-    traces under ``enable_x64`` so strong float64 cannot be masked.
+    traces under ``jax.enable_x64`` so strong float64 cannot be masked.
     """
     def trace():
         return jax.make_jaxpr(lambda *a: fn(*a, **kwargs))(*args)
 
     if x64:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             closed = trace()
     else:
         closed = trace()

@@ -22,14 +22,6 @@ from repro.core.precision import DoubleF32
 from repro.core.rounding import quantize_mantissa
 
 
-def _to_bf16(x: jax.Array, rounding: str) -> jax.Array:
-    if rounding == "rne":
-        return x.astype(jnp.bfloat16)
-    # Paper-faithful rounding: quantize the f32 mantissa to bf16's 7 explicit
-    # bits with the selected scheme, then the bf16 cast is exact.
-    return quantize_mantissa(x, 7, rounding).astype(jnp.bfloat16)
-
-
 def split_limbs(x, k: int, rounding: str = "rne") -> jax.Array:
     """Split ``x`` (f32 array or DoubleF32) into ``k`` bf16 limbs.
 
@@ -47,9 +39,14 @@ def split_limbs(x, k: int, rounding: str = "rne") -> jax.Array:
             # (The residual r is ~0 here; adding first keeps any leftovers.)
             r = r + lo
             lo = None
-        li = _to_bf16(r, rounding)
-        limbs.append(li)
-        r = r - li.astype(jnp.float32)
+        # Round to bf16's 7 explicit mantissa bits on the bit pattern, kept
+        # in f32 (exact in bf16).  A bare f32 -> bf16 cast is not enough on
+        # the TPU: inside a fusion XLA may keep the cast value at f32, so the
+        # residual r - f32(bf16(r)) is 0 and every limb past the first
+        # vanishes.
+        li = quantize_mantissa(r, 7, rounding)
+        limbs.append(li.astype(jnp.bfloat16))
+        r = r - li
     if lo is not None and k < 3:
         pass  # lo never injected: k-limb mode cannot see it (by design).
     return jnp.stack(limbs)

@@ -22,6 +22,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.rounding import quantize_mantissa
+
 
 class Mode(enum.IntEnum):
     """Precision mode select (the paper's 3 mode-select bits)."""
@@ -144,7 +146,9 @@ def _limbs_needed(x: jax.Array, max_limbs: int, tol: float) -> jax.Array:
     # whose residual is within tolerance is  max_limbs - (#ok levels) + 1.
     n_ok = jnp.int32(0)
     for _ in range(max_limbs):
-        limb = r.astype(jnp.bfloat16).astype(jnp.float32)
+        # integer-domain rounding: a bare bf16 round trip may be elided on
+        # the TPU (see core.limb.split_limbs), which would zero the residual
+        limb = quantize_mantissa(r, 7, "rne")
         r = r - limb
         ok = jnp.max(jnp.abs(r)) <= tol * scale
         n_ok = n_ok + ok.astype(jnp.int32)

@@ -19,8 +19,8 @@ Implementation paths:
         per-tile mode map rides along as a runtime argument, so one fused
         dispatch serves every f32-ladder mode (and mixed-mode maps) with no
         ``lax.switch`` — uniform maps are bit-identical to impl='pallas'.
-  * ``impl='native'`` — plain f32 jnp.dot reference execution (numerically
-        ~= M24); used for fast CPU end-to-end examples.
+  * ``impl='native'`` — plain f32 dot at ``Precision.HIGHEST`` (numerically
+        ~= M24 on every backend); used for fast CPU end-to-end examples.
 
 High modes (M32/M48) accumulate their partial products with Neumaier
 compensation over K-chunks, because a plain f32 accumulator would cap the
@@ -166,11 +166,14 @@ def mp_einsum(
             raise ValueError("AUTO requires mp_matmul_runtime / mp_einsum_runtime")
         av = a.hi + a.lo if isinstance(a, DoubleF32) else a
         bv = b.hi + b.lo if isinstance(b, DoubleF32) else b
+        # HIGHEST keeps 'native' at f32 fidelity on every backend: a
+        # default-precision f32 dot is one bf16 pass (~M8) on the TPU
         return jnp.einsum(
             eq,
             av.astype(jnp.float32),
             bv.astype(jnp.float32),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
     _check_mode_operands(mode, a, b)
     return _limb_einsum(eq, a, b, MODE_LIMBS[mode], rounding)

@@ -23,15 +23,7 @@ BATCH_AXES = ("pod", "data")
 
 
 def _active_mesh():
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        # jax < 0.5: the abstract-mesh accessor only exists privately and
-        # returns () when no mesh context is active.  Meshless paths (CPU
-        # smoke tests, single-device serving) just need the no-op branch.
-        from jax._src import mesh as _mesh_lib
-
-        get = getattr(_mesh_lib, "get_abstract_mesh", lambda: None)
-    mesh = get()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not getattr(mesh, "axis_names", None) or getattr(mesh, "empty", False):
         return None
     return mesh

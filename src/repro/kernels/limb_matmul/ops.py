@@ -53,6 +53,42 @@ def _limb_matmul(
     bk: int,
     interpret: bool,
 ) -> jax.Array:
+    return _limb_matmul_vjp(a, b, (k, rounding, bm, bn, bk, interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _limb_matmul_vjp(a, b, cfg):
+    """Differentiable core: ``pallas_call`` has no usable JVP when compiled
+    for the TPU, so the backward pass is written out instead.  It runs the
+    same kernel at the same limb count and rounding on the transposed
+    operands (``da = g @ b.T``, ``db = a.T @ g``), keeping the paper's
+    multiplier in both passes; the rounding pre-pass is straight-through.
+    """
+    return _forward(a, b, *cfg)
+
+
+def _limb_matmul_fwd(a, b, cfg):
+    return _forward(a, b, *cfg), (a, b)
+
+
+def _limb_matmul_bwd(cfg, res, g):
+    a, b = res
+    k, rounding, bm, bn, bk, interpret = cfg
+    kdim, n = b.shape
+    g = g.astype(jnp.float32)
+    # each backward GEMM keeps the forward's tile roles: the contraction of
+    # da is N (block bn), the contraction of db is M (block bm)
+    da = _limb_matmul_vjp(g, b.astype(jnp.float32).T,
+                          (k, rounding, bm, bk, bn, interpret))
+    db = _limb_matmul_vjp(a.reshape(-1, kdim).astype(jnp.float32).T,
+                          g.reshape(-1, n), (k, rounding, bk, bn, bm, interpret))
+    return da.astype(a.dtype), db.astype(b.dtype)
+
+
+_limb_matmul_vjp.defvjp(_limb_matmul_fwd, _limb_matmul_bwd)
+
+
+def _forward(a, b, k, rounding, bm, bn, bk, interpret):
     if rounding != "rne":
         from repro.kernels.quantize_mantissa.ops import quantize_mantissa_op
 

@@ -48,6 +48,49 @@ def tile_grid(
     jax.jit, static_argnames=("kmax", "rounding", "bm", "bn", "bk", "interpret")
 )
 def _tile_matmul(a, b, mode_map, *, kmax, rounding, bm, bn, bk, interpret):
+    return _tile_matmul_vjp(a, b, mode_map, (kmax, rounding, bm, bn, bk, interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tile_matmul_vjp(a, b, mode_map, cfg):
+    """Differentiable core (``pallas_call`` has no usable JVP when compiled
+    for the TPU).  The backward pass replays every forward block product at
+    its own mode: ``da = g @ b.T`` and ``db = a.T @ g`` run the same kernel
+    with 3D maps whose entry for (output tile, contraction slab) is the
+    forward map's entry for that (i, j, kk) block.  The map itself gets no
+    cotangent; the rounding pre-pass is straight-through.
+    """
+    return _forward(a, b, mode_map, *cfg)
+
+
+def _tile_matmul_fwd(a, b, mode_map, cfg):
+    return _forward(a, b, mode_map, *cfg), (a, b, mode_map)
+
+
+def _tile_matmul_bwd(cfg, res, g):
+    a, b, mode_map = res
+    kmax, rounding, bm, bn, bk, interpret = cfg
+    kdim, n = b.shape
+    a2 = a.reshape(-1, kdim).astype(jnp.float32)
+    (gm, gn, gk), (bm_, bn_, bk_) = tile_grid(a2.shape[0], n, kdim, bm=bm, bn=bn, bk=bk)
+    if mode_map.ndim == 2:
+        mode_map = jnp.broadcast_to(mode_map[:, :, None], (gm, gn, gk))
+    g = g.astype(jnp.float32)
+    # da[i, kk] sums over j, db[kk, j] sums over i: the clamped forward
+    # blocks are reused, so both grids line up with the forward map's axes
+    da = _tile_matmul_vjp(g, b.astype(jnp.float32).T,
+                          jnp.transpose(mode_map, (0, 2, 1)),
+                          (kmax, rounding, bm_, bk_, bn_, interpret))
+    db = _tile_matmul_vjp(a2.T, g.reshape(-1, n),
+                          jnp.transpose(mode_map, (2, 1, 0)),
+                          (kmax, rounding, bk_, bn_, bm_, interpret))
+    return da.astype(a.dtype), db.astype(b.dtype), None
+
+
+_tile_matmul_vjp.defvjp(_tile_matmul_fwd, _tile_matmul_bwd)
+
+
+def _forward(a, b, mode_map, kmax, rounding, bm, bn, bk, interpret):
     if rounding != "rne":
         from repro.kernels.quantize_mantissa.ops import quantize_mantissa_op
 

@@ -109,7 +109,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, policy: str = "paper_b
             model_flops_global=mf,
             useful_flops_ratio=useful_ratio,
             roofline_fraction_estimate=(
-                (mf / chips / hlo_cost.PEAK_FLOPS) / dom_t if dom_t else 0.0
+                (mf / chips / hlo_cost.device_peaks().flops) / dom_t if dom_t else 0.0
             ),
         )
     except Exception as e:  # a failure here is a sharding/memory bug

@@ -233,19 +233,56 @@ def parse_hlo_cost(hlo: str, entry_hint: str | None = None) -> Cost:
 
 
 # --------------------------------------------------------------------------
-# Roofline terms (TPU v5e constants from the assignment)
+# Roofline terms: published per-chip peaks, keyed by device kind
 # --------------------------------------------------------------------------
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # interconnect bytes/s per link
+
+
+#: Published peaks per ``jax.Device.device_kind``.  "TPU v5 lite" is the
+#: TPU v5e (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s of interconnect per chip over 4 links.
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+#: The chip the cost model plans for on a host without a TPU (CPU tests,
+#: dry runs): the deployment target, not the host.
+REFERENCE_KIND = "TPU v5 lite"
+
+
+def peaks_for(kind: str) -> DevicePeaks:
+    """Peaks of one device kind; a kind missing from the table is an error,
+    never a silent v5e."""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {kind!r}; add it to "
+            f"repro.launch.hlo_cost.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)})"
+        ) from None
+
+
+def device_peaks() -> DevicePeaks:
+    """Peaks of the attached TPU, or of ``REFERENCE_KIND`` when the host has
+    no TPU."""
+    import jax
+
+    dev = jax.devices()[0]
+    return peaks_for(dev.device_kind if dev.platform == "tpu" else REFERENCE_KIND)
 
 
 def roofline_terms(cost: Cost) -> dict:
     """Seconds per term, per chip (cost is already per-device)."""
-    t_compute = cost.flops / PEAK_FLOPS
-    t_memory = cost.hbm_bytes / HBM_BW
-    t_collective = cost.collective_bytes / ICI_BW
+    peaks = device_peaks()
+    t_compute = cost.flops / peaks.flops
+    t_memory = cost.hbm_bytes / peaks.hbm_bw
+    t_collective = cost.collective_bytes / peaks.ici_bw
     dominant = max(
         ("compute", t_compute), ("memory", t_memory), ("collective", t_collective),
         key=lambda kv: kv[1],

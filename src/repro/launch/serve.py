@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core.policy import PRESETS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.plan import plan_cache_stats
 from repro.serve import Request, ServeEngine, ragged_requests
@@ -152,6 +153,7 @@ def main() -> None:
                          "this path (implies --trace)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     cfg = cfg.with_policy(PRESETS[args.policy])

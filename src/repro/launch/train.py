@@ -20,13 +20,16 @@ from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core.policy import PRESETS
 from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.distributed.sharding import param_shardings, replicated
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import adamw
 from repro.train.loop import LoopConfig, resume_or_init, train_loop
 from repro.train.step import TrainConfig, init_train_state, make_train_step
 
 
-def main() -> None:
+def run(argv: list[str] | None = None) -> tuple[dict, list[dict]]:
+    """Parse ``argv`` (default: the command line), train, and return the
+    final state and the per-step metrics history."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--smoke", action="store_true", help="reduced CPU-scale config")
@@ -62,7 +65,8 @@ def main() -> None:
                     help="adapt: max tolerated relative grad-norm drift")
     ap.add_argument("--slo-ms", type=float, default=0.0,
                     help="adapt: per-step latency target in ms (0 = none)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     cfg = cfg.with_policy(PRESETS[args.policy])
@@ -124,11 +128,14 @@ def main() -> None:
         }
         shardings = ((state_shard, None, None) if schedule is not None
                      else (state_shard, None))
+        # the state is created already sharded: initialising it eagerly
+        # would place all of it on the first device before the step moves it
+        init_sharded = jax.jit(
+            lambda: init_train_state(model, jax.random.key(0), tcfg),
+            out_shardings=state_shard)
         with jax.set_mesh(mesh):
             step = jax.jit(step_fn, in_shardings=shardings, donate_argnums=0)
-            start, state = resume_or_init(
-                mgr, lambda: init_train_state(model, jax.random.key(0), tcfg), state_shard
-            )
+            start, state = resume_or_init(mgr, init_sharded, state_shard)
     else:
         step = jax.jit(step_fn, donate_argnums=0)
         start, state = resume_or_init(
@@ -169,6 +176,11 @@ def main() -> None:
                 timeline.append(m)
         print(f"precision schedule: {' -> '.join(timeline)} "
               f"({schedule.table.switches} switches)")
+    return state, hist
+
+
+def main() -> None:
+    run()
 
 
 class _null:

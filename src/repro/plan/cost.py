@@ -18,19 +18,16 @@ reconfiguration, lifted to the block-algorithm level:
   * **impl** — 'native' (plain f32 dot: 1 pass, no limb traffic, fidelity
     ~= M24), 'xla' (limb algebra in HBM), 'pallas' (fused limb extraction).
 
-The machine-balance constants are the same ones the dry-run roofline uses
-(repro.launch.hlo_cost: TPU v5e peak FLOPs / HBM BW) — the planner and the
-HLO-derived roofline read from one set of numbers, per the fold-the-
-heuristics-into-one-place goal of the planner PR.
+The machine-balance constants come from the same per-device-kind peaks
+table the dry-run roofline uses (repro.launch.hlo_cost.DEVICE_PEAKS) — the
+planner and the HLO-derived roofline read from one set of numbers.
 """
 from __future__ import annotations
 
 import dataclasses
 
-# Machine balance: folded in from the dry-run roofline (launch/hlo_cost.py).
-from repro.launch.hlo_cost import HBM_BW, PEAK_FLOPS
-
 from repro.core.precision import MODE_PASSES, Mode
+from repro.launch.hlo_cost import REFERENCE_KIND, device_peaks, peaks_for
 
 F32_BYTES = 4
 BF16_BYTES = 2
@@ -58,18 +55,26 @@ def _ceil_to(x: int, m: int) -> int:
 class MachineBalance:
     """The roofline's machine constants: peak FLOP/s and HBM bandwidth.
 
-    The defaults are the hand-entered TPU-balance numbers shared with the
+    The defaults are the reference chip's published peaks, shared with the
     dry-run roofline (launch/hlo_cost.py).  ``fit_balance`` re-fits both from
     a measured tuning table (repro.tune) so the planner can rank candidates
     against the machine it actually runs on (DESIGN.md section Autotuner).
     """
 
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
+    peak_flops: float = peaks_for(REFERENCE_KIND).flops
+    hbm_bw: float = peaks_for(REFERENCE_KIND).hbm_bw
     source: str = "default"
 
 
 DEFAULT_BALANCE = MachineBalance()
+
+
+def device_balance() -> MachineBalance:
+    """Balance of the attached TPU (by device kind; an unknown kind raises),
+    or ``DEFAULT_BALANCE`` on a host without one."""
+    peaks = device_peaks()
+    return dataclasses.replace(DEFAULT_BALANCE, peak_flops=peaks.flops,
+                               hbm_bw=peaks.hbm_bw)
 
 
 def fit_balance(samples, *, source: str = "fit") -> MachineBalance:
@@ -172,8 +177,8 @@ def estimate(
     depth: int,
     *,
     align: int = 128,
-    peak_flops: float = PEAK_FLOPS,
-    hbm_bw: float = HBM_BW,
+    peak_flops: float = DEFAULT_BALANCE.peak_flops,
+    hbm_bw: float = DEFAULT_BALANCE.hbm_bw,
 ) -> CostEstimate:
     """Roofline estimate for C = A (m, k) @ B (k, n) under one candidate."""
     (mp_, kp, np_), add_flops, add_bytes = strassen_overhead(m, k, n, depth, align)

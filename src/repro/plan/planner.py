@@ -411,8 +411,8 @@ def plan_matmul(
     # With a tuning table active, the roofline fallback runs on the table's
     # re-fit machine constants (cost.fit_balance) so measured and modeled
     # candidate times stay commensurable in one ranking; without one, the
-    # hand-entered TPU-balance defaults apply.
-    balance = table.balance if table is not None else cost_lib.DEFAULT_BALANCE
+    # published peaks of the attached chip (or the reference chip) apply.
+    balance = table.balance if table is not None else cost_lib.device_balance()
     best: tuple[tuple, CostEstimate, str, int, str, Any] | None = None
     # Magnitude maps are defined on the whole GEMM's tile grid; Strassen's
     # block adds/subtracts would scramble the per-tile magnitudes the map

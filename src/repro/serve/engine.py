@@ -69,12 +69,14 @@ def _plan_phase(model: LanguageModel, tokens: int, accuracy: float,
     if (
         base.impl == "native"
         and policy.impl == "xla"
+        and (backend or jax.default_backend()) != "tpu"
         and not any(p.mode in DF32_MODES for p in plans.values())
     ):
         # keep the fast CPU execution path when the base policy chose it and
-        # the planner has no better limb impl to offer — but never for DF32
-        # modes, where 'xla' IS the limb engine and 'native' (plain f32)
-        # would break the accuracy budget
+        # the planner has no better limb impl to offer — but never on the
+        # TPU, where 'native' (HIGHEST f32) costs more passes than the
+        # planned modes, and never for DF32 modes, where 'xla' IS the limb
+        # engine and 'native' (plain f32) would break the accuracy budget
         policy = policy.with_impl("native")
     return LanguageModel(model.cfg.with_policy(policy)), plans
 

@@ -50,6 +50,25 @@ class TestLimbSplit:
         assert errs[1] < 2**-15
         assert errs[2] == 0.0
 
+    @pytest.mark.parametrize("fn", ["split_limbs", "auto_mode"])
+    def test_limbs_round_in_integer_domain(self, fn):
+        # On the TPU, XLA may keep a bf16 cast at f32 inside a fusion, which
+        # zeroes x - f32(bf16(x)) and silently turns M24 into M8.  Limbs are
+        # therefore rounded on the bit pattern: no bf16 -> f32 round trip may
+        # feed a residual, and each limb costs one bitcast.
+        x = jnp.ones((8, 16), jnp.float32)
+        trace = {
+            "split_limbs": lambda: jax.make_jaxpr(
+                lambda v: limb_lib.split_limbs(v, 3))(x),
+            "auto_mode": lambda: jax.make_jaxpr(auto_mode)(x, x),
+        }[fn]()
+        prims = [e.primitive.name for e in trace.jaxpr.eqns]
+        widen = [e for e in trace.jaxpr.eqns
+                 if e.primitive.name == "convert_element_type"
+                 and e.invars[0].aval.dtype == jnp.bfloat16]
+        assert not widen
+        assert prims.count("bitcast_convert_type") >= 3
+
     def test_product_terms_karatsuba_truncation(self):
         # |{(i,j): i+j<k}| = k(k+1)/2 — the retained Karatsuba economy.
         for k in (1, 2, 3, 4, 6):
@@ -107,6 +126,16 @@ class TestModeLadder:
         out_e = mp_einsum("mk,kn->mn", a, b, Mode.M16)
         out_m = mp_matmul(a, b, Mode.M16)
         np.testing.assert_array_equal(np.asarray(out_e), np.asarray(out_m))
+
+    def test_native_traces_highest_precision(self):
+        # a default-precision f32 dot is one bf16 pass on the TPU; 'native'
+        # promises f32 fidelity, so its dot must ask for HIGHEST
+        a, b = jnp.ones((4, 8)), jnp.ones((8, 3))
+        jaxpr = jax.make_jaxpr(
+            lambda a_, b_: mp_einsum("mk,kn->mn", a_, b_, impl="native"))(a, b)
+        dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+        assert len(dots) == 1
+        assert dots[0].params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
 
     def test_batched_lhs(self, rng):
         a = _rand(rng, 4, 6, 32)

@@ -5,17 +5,9 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# These cells drive the explicit-mesh API; on older jax they cannot even
-# construct the mesh.  CI installs current jax[cpu] and runs them for real.
-requires_set_mesh = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")),
-    reason="needs jax.set_mesh / jax.sharding.AxisType (jax >= 0.5)",
-)
 
 
 def run_with_devices(code: str, n: int = 8) -> str:
@@ -31,7 +23,6 @@ def run_with_devices(code: str, n: int = 8) -> str:
 
 
 @pytest.mark.slow
-@requires_set_mesh
 class TestSharded:
     def test_sharded_train_step_matches_single_device(self):
         run_with_devices("""

@@ -3,7 +3,14 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.hlo_cost import Cost, parse_hlo_cost, roofline_terms
+from repro.launch.hlo_cost import (
+    REFERENCE_KIND,
+    Cost,
+    device_peaks,
+    parse_hlo_cost,
+    peaks_for,
+    roofline_terms,
+)
 
 
 def _flops(fn, *shapes):
@@ -55,10 +62,6 @@ class TestParser:
         # fwd + recompute + 2 bwd matmuls per layer = ~4 units (allow fusion slack)
         assert fl >= 4 * 3 * 2 * 32**3
 
-    @pytest.mark.skipif(
-        not (hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")),
-        reason="needs jax.set_mesh / jax.sharding.AxisType (jax >= 0.5)",
-    )
     def test_collective_bytes_multi_device(self):
         import os
         import subprocess
@@ -97,3 +100,17 @@ class TestParser:
         c = Cost(flops=1.0, hbm_bytes=1.0, collective_bytes=50e9 * 3)
         t = roofline_terms(c)
         assert t["dominant"] == "collective" and t["t_collective_s"] == pytest.approx(3.0)
+
+
+class TestPeaks:
+    def test_v5e_published_peaks(self):
+        p = peaks_for("TPU v5 lite")
+        assert (p.flops, p.hbm_bw) == (197e12, 819e9)
+
+    def test_unknown_kind_raises(self):
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks_for("TPU v4")
+
+    def test_host_without_tpu_plans_for_reference_chip(self):
+        assert jax.devices()[0].platform != "tpu"
+        assert device_peaks() == peaks_for(REFERENCE_KIND)

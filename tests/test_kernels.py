@@ -1,6 +1,7 @@
 """Per-kernel shape/dtype sweeps vs ref.py oracles (interpret mode on CPU)."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.limb_matmul.limb_matmul import limb_matmul_dd_pallas
@@ -35,6 +36,24 @@ class TestLimbMatmulKernel:
         )
         scale = max(np.abs(ref).max(), 1e-6)
         np.testing.assert_allclose(out / scale, ref / scale, atol=2e-6)
+
+    @pytest.mark.parametrize("k", [1, 2, 3])
+    def test_grad_within_mode_error(self, rng, k):
+        # the backward pass runs the kernel at the forward's limb count, so
+        # both cotangents carry that mode's error, no more
+        from repro.core.precision import Mode
+        from repro.plan.cost import MODE_REL_ERROR
+
+        a = jnp.asarray(rng.standard_normal((2, 20, 96)).astype(np.float32))
+        b = jnp.asarray(rng.standard_normal((96, 40)).astype(np.float32))
+        w = jnp.asarray(rng.standard_normal((2, 20, 40)).astype(np.float32))
+        got = jax.grad(lambda a_, b_: (limb_matmul(
+            a_, b_, k, interpret=True, bm=16, bn=32, bk=32) * w).sum(), (0, 1))(a, b)
+        ref = jax.grad(lambda a_, b_: (jnp.einsum(
+            "bmk,kn->bmn", a_, b_, precision="highest") * w).sum(), (0, 1))(a, b)
+        for g, r in zip(got, ref):
+            g, r = np.asarray(g, np.float64), np.asarray(r, np.float64)
+            assert np.abs(g - r).max() / np.abs(r).max() < MODE_REL_ERROR[Mode(k)]
 
     def test_batched_lhs(self, rng):
         a = jnp.asarray(rng.standard_normal((2, 3, 48)).astype(np.float32))

@@ -69,10 +69,7 @@ class TestEconomy:
             .compile()
         )
         def flops(compiled):
-            ca = compiled.cost_analysis()
-            if isinstance(ca, list):  # jax < 0.5 returns [dict]
-                ca = ca[0]
-            return ca["flops"]
+            return compiled.cost_analysis()["flops"]
 
         fc = flops(classical)
         fs = flops(strassen)

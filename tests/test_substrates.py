@@ -179,3 +179,28 @@ class TestStragglerMonitor:
         assert not mon.flagged
         assert mon.observe(99, 1.5)  # 15x step time -> straggler
         assert mon.flagged and mon.flagged[-1][0] == 99
+
+
+class TestCompileCache:
+    def _updates(self, monkeypatch):
+        seen = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda name, value: seen.__setitem__(name, value))
+        return seen
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        seen = self._updates(monkeypatch)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert seen == {}
+
+    def test_default_dir_is_fixed_inside_the_checkout(self, monkeypatch):
+        from repro.launch.compile_cache import DEFAULT_DIR, enable_compile_cache
+
+        seen = self._updates(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert enable_compile_cache() == os.path.join(repo, ".jax_cache")
+        assert seen == {"jax_compilation_cache_dir": str(DEFAULT_DIR)}
