@@ -1,0 +1,11 @@
+"""Attention: device time of the decode step's operations charged to
+``attention`` (the cache read and flash attention, its score and value
+contractions included), per decode step (the engine's jitted masked step),
+mean over the traced window, ms.  The charge rule: ``chipbench.scopes``."""
+from __future__ import annotations
+
+from chipbench import scopes
+
+
+def read(ctx, suffix):
+    return scopes.of(ctx).decode_scope_ms(ctx.trace).get("attention")

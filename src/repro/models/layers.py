@@ -41,7 +41,16 @@ def pmm(x: Array, w: Array, op: str, policy: PrecisionPolicy) -> Array:
     bit-identical to the pallas branch the ``lax.switch`` would have
     picked; other impls keep the N-branch switch.  Only f32-ladder plans
     are switchable; DF32/Strassen plans keep their static path.
+
+    The call runs under ``jax.named_scope("gemm/<op>")``, so its device ops
+    carry the op class in their HLO ``op_name`` (DESIGN.md section
+    Observability).
     """
+    with jax.named_scope(f"gemm/{op}"):
+        return _pmm(x, w, op, policy)
+
+
+def _pmm(x: Array, w: Array, op: str, policy: PrecisionPolicy) -> Array:
     plan = plan_matmul(
         tuple(x.shape),
         tuple(w.shape),
@@ -71,6 +80,12 @@ def pmm(x: Array, w: Array, op: str, policy: PrecisionPolicy) -> Array:
 
 
 def pein(eq: str, a: Array, b: Array, op: str, policy: PrecisionPolicy) -> Array:
+    """Policy-routed einsum (see :func:`pmm`), under ``gemm/<op>``."""
+    with jax.named_scope(f"gemm/{op}"):
+        return _pein(eq, a, b, op, policy)
+
+
+def _pein(eq: str, a: Array, b: Array, op: str, policy: PrecisionPolicy) -> Array:
     mode = policy.mode_for(op)
     rt_mode = runtime_mode_for(op)
     if (
@@ -93,9 +108,11 @@ def pein(eq: str, a: Array, b: Array, op: str, policy: PrecisionPolicy) -> Array
 
 
 def plinear(x: Array, p: Params, op: str, policy: PrecisionPolicy) -> Array:
-    out = pmm(x, p["w"], op, policy)
-    if "b" in p:
-        out = out + p["b"].astype(out.dtype)
+    """:func:`pmm` plus the bias, both under ``gemm/<op>``."""
+    with jax.named_scope(f"gemm/{op}"):
+        out = _pmm(x, p["w"], op, policy)
+        if "b" in p:
+            out = out + p["b"].astype(out.dtype)
     return out
 
 
@@ -618,9 +635,26 @@ def attention_apply(
         v = plinear(enc, p["wv"], "qkv", policy).reshape(b, enc.shape[1], hkv, hd)
         causal = False
 
+    q_offset = None
     if cache is not None and kv_override is None:
         q_offset = cache.length
         cache = kv_append(cache, k, v)  # KVLayout dispatch (paged/per-slot/shared)
+    # the attention core (cache read and flash attention; not the
+    # projections) under one scope: its score and value contractions
+    # count as attention, not as weight GEMMs
+    with jax.named_scope("attention"):
+        out = _attention_core(q, k, v, policy, cfg, cache, q_offset,
+                              causal, window)
+    out = pmm(out.reshape(b, s, hq * hd), p["wo"]["w"], "out", policy)
+    return out, cache
+
+
+def _attention_core(q, k, v, policy, cfg, cache, q_offset, causal, window):
+    """Flash attention over the fresh K/V (prefill, no cache) or over the
+    cache read after this step's append (decode: ``q_offset`` is the cache
+    length before it)."""
+    s = q.shape[1]
+    if q_offset is not None:
         if s > 1:
             # prefill: attend over the fresh full-length K/V (the window
             # cache may be smaller than the prompt; it keeps only the tail)
@@ -651,8 +685,7 @@ def attention_apply(
         out = flash_attention(
             q, k, v, policy, causal=causal, window=window, chunk=cfg.attn_chunk
         )
-    out = pmm(out.reshape(b, s, hq * hd), p["wo"]["w"], "out", policy)
-    return out, cache
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -126,10 +126,20 @@ def mamba2_apply(
 ) -> tuple[Array, SSMState | None]:
     """x: (B, S, d_model).  state!=None -> decode (S small, sequential)."""
     policy = cfg.policy
-    b, s, _ = x.shape
+    proj = pein("bsd,de->bse", x, p["in_proj"]["w"], "ssm_in", policy)
+    # the mixer between the projections (conv, SSD scan or recurrence,
+    # state update, gated norm) under one scope: its 'ssd' contractions
+    # count as the mixer, not as weight GEMMs
+    with jax.named_scope("ssm"):
+        y, new_state = _mixer(proj, p, cfg, state)
+    out = pein("bse,ed->bsd", y, p["out_proj"]["w"], "ssm_out", policy)
+    return out, new_state
+
+
+def _mixer(proj: Array, p: Params, cfg, state: SSMState | None):
+    b, s, _ = proj.shape
     d_inner, n_heads = _dims(cfg)
     n = cfg.ssm_state
-    proj = pein("bsd,de->bse", x, p["in_proj"]["w"], "ssm_in", policy)
     z, xbc, dt_raw = jnp.split(proj, [d_inner, 2 * d_inner + 2 * n], axis=-1)
     conv_in = xbc  # (B, S, d_inner + 2N)
     conv_out, conv_state = causal_conv1d(
@@ -179,8 +189,7 @@ def mamba2_apply(
     y = y * jax.nn.silu(z)
     var = jnp.mean(y * y, axis=-1, keepdims=True)
     y = y * jax.lax.rsqrt(var + cfg.norm_eps) * (1.0 + p["norm_scale"])
-    out = pein("bse,ed->bsd", y, p["out_proj"]["w"], "ssm_out", policy)
-    return out, new_state
+    return y, new_state
 
 
 def ssm_state_init(cfg, batch: int) -> SSMState:

@@ -3,10 +3,11 @@
 The Chrome document uses only self-balancing phases — ``"X"`` (complete
 spans with explicit ``dur``), ``"i"`` (instants), ``"C"`` (counters) and
 ``"M"`` (metadata) — so a truncated ring can never produce unbalanced
-begin/end pairs.  Layout: pid 1 is the engine (dispatch spans and decision
-instants on tid 0), pid 2 holds one thread per request (tid = rid) whose
-spans are the request lifecycle reconstructed from submit/admit/preempt/
-resume/done events.  Timestamps are microseconds relative to the first
+begin/end pairs.  Layout: pid 1 is the engine (tid 0: every event carrying
+``dur_ms`` as a complete span starting at its ``ts`` — the tracer's nested
+spans — and decision instants), pid 2 holds one thread per request
+(tid = rid) whose spans are the request lifecycle reconstructed from
+submit/admit/preempt/resume/done events.  Timestamps are microseconds relative to the first
 event.
 
 ``validate_chrome`` is the schema + well-formedness gate CI runs on
@@ -37,11 +38,7 @@ _INSTANT_KINDS = {
     "cow_fork": "t",
     "prefix_share": "t",
     "recompile": "p",
-    "spec_round": "t",
 }
-
-#: engine events with a ``dur_ms`` payload rendered as complete spans
-_SPAN_KINDS = ("decode_step", "prefill")
 
 
 def _args(e: Event) -> dict:
@@ -72,15 +69,15 @@ def to_chrome(events: list[Event], counters: dict | None = None,
     def us(ts: float) -> float:
         return (ts - t0) * 1e6
 
-    # -- engine track: dispatch spans, instants, counters --------------------
+    # -- engine track: spans, instants, counters ----------------------------
     for e in events:
-        if e.kind in _SPAN_KINDS:
-            dur_us = float((e.data or {}).get("dur_ms", 0.0)) * 1e3
+        dur_ms = (e.data or {}).get("dur_ms")
+        if dur_ms is not None:
             out.append({
                 "ph": "X", "pid": 1, "tid": 0, "name": e.kind, "cat": "engine",
-                "ts": max(0.0, us(e.ts) - dur_us), "dur": dur_us,
+                "ts": us(e.ts), "dur": float(dur_ms) * 1e3,
                 "args": _args(e)})
-            if e.kind == "decode_step" and "n_active" in (e.data or {}):
+            if e.kind == "decode" and "n_active" in e.data:
                 out.append({
                     "ph": "C", "pid": 1, "tid": 0, "name": "active_slots",
                     "ts": us(e.ts),

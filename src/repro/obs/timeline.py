@@ -16,8 +16,8 @@ from repro.obs.tracer import Event
 def precision_timeline(events: list[Event]) -> list[dict]:
     """Rows ``{step, mode, sites, draft_shift, tier_keep, tier_depth}``,
     one per step at which any axis changed (values carry forward between
-    rows).  ``mode``/``sites`` come from mode_switch events (decode_step
-    events seed the initial mode label), draft_shift and tier_tick fill the
+    rows).  ``mode``/``sites`` come from mode_switch events (``decode``
+    spans seed the initial mode label), draft_shift and tier_tick fill the
     other axes."""
     state = {"mode": None, "sites": None, "draft_shift": None,
              "tier_keep": None, "tier_depth": None}
@@ -31,7 +31,7 @@ def precision_timeline(events: list[Event]) -> list[dict]:
 
     for e in events:
         data = e.data or {}
-        if e.kind == "decode_step":
+        if e.kind == "decode":
             mode = data.get("mode")
             if mode is not None and state["mode"] is None:
                 state["mode"] = mode

@@ -18,8 +18,13 @@ Event taxonomy (DESIGN.md section Observability):
               span skeleton; step stamps follow the scheduler clock and the
               stream replays through the tests/scheduler_model.py invariant
               harness (consumer mode);
-  engine      decode_step, spec_round, prefill, recompile — per-dispatch
-              wall time and token accounting (repro.obs.profile);
+  spans       step, schedule, admit > prefill > scatter, decode >
+              (decode.inputs, decode.dispatch, decode.sync, emit),
+              spec_round > (the same four), adapt — the engine's phases
+              as nested spans (``Tracer.span``): one event at the span's
+              start carrying ``dur_ms`` and its ``parent`` kind, and a
+              ``serve.<kind>`` annotation on the profiler's clock;
+  engine      recompile — jit cache growth (repro.obs.profile);
   decisions   adapt_decision, mode_switch, draft_shift, tier_tick,
               preempt_plan, admit_defer, admit_refuse, page_evict, cow_fork,
               prefix_share — every reconfiguration with its *cause*.
@@ -32,6 +37,8 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
+
+import jax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +62,8 @@ class TraceConfig:
 class Event:
     """One typed trace record.  ``ts`` is the tracer's monotonic clock
     (seconds, ``time.perf_counter``); ``step`` is the scheduler clock the
-    event belongs to; ``cause`` names *why* for decision events."""
+    event belongs to; ``cause`` names *why* for decision events.  An event
+    whose data carries ``dur_ms`` is a span: ``ts`` is its start."""
 
     ts: float
     step: int
@@ -83,6 +91,8 @@ class Tracer:
         #: ``step()`` so emit sites need not thread the clock through
         self.step = 0
         self.emitted = 0
+        #: events of the spans open now, innermost last
+        self._open: list[Event] = []
 
     @property
     def dropped(self) -> int:
@@ -96,6 +106,16 @@ class Tracer:
         self.events.append(Event(
             ts=self.clock(), step=self.step if step is None else int(step),
             kind=kind, rid=rid, slot=slot, cause=cause, data=data or None))
+
+    def span(self, kind: str, *, rid: int | None = None,
+             slot: int | None = None, **data) -> "_Span":
+        """A context manager timing one engine phase.  On entry it appends
+        one event of ``kind`` (``ts`` the start, ``data["parent"]`` the kind
+        of the enclosing span or None) and yields it, so a site can add
+        counts it learns inside; on exit it sets ``data["dur_ms"]``.  The
+        block also runs under ``jax.profiler.TraceAnnotation("serve.<kind>")``,
+        so a profiler session holds the span on the device trace's clock."""
+        return _Span(self, kind, rid, slot, data)
 
     def inc(self, name: str, value: float = 1.0) -> None:
         self.counters[name] = self.counters.get(name, 0.0) + value
@@ -144,6 +164,53 @@ class Tracer:
                 f"capacity {self.config.capacity}) | {body or '-'}")
 
 
+class _Span:
+    """One span of a :class:`Tracer` (see :meth:`Tracer.span`)."""
+
+    __slots__ = ("tracer", "kind", "rid", "slot", "data", "event", "annotation")
+
+    def __init__(self, tracer: Tracer, kind: str, rid, slot, data: dict):
+        self.tracer, self.kind, self.rid, self.slot = tracer, kind, rid, slot
+        self.data = data
+
+    def __enter__(self) -> Event:
+        tr = self.tracer
+        args = {k: v for k, v in (("rid", self.rid), ("slot", self.slot))
+                if v is not None}
+        self.annotation = jax.profiler.TraceAnnotation(
+            f"serve.{self.kind}", **args, **self.data)
+        self.annotation.__enter__()
+        self.data["parent"] = tr._open[-1].kind if tr._open else None
+        ev = Event(ts=tr.clock(), step=tr.step, kind=self.kind, rid=self.rid,
+                   slot=self.slot, data=self.data)
+        tr.emitted += 1
+        tr.events.append(ev)
+        tr._open.append(ev)
+        self.event = ev
+        return ev
+
+    def __exit__(self, *exc) -> None:
+        tr, ev = self.tracer, self.event
+        ev.data["dur_ms"] = (tr.clock() - ev.ts) * 1e3
+        tr._open.pop()
+        self.annotation.__exit__(*exc)
+
+
+class _NullSpan:
+    """The span of :class:`NullTracer`: enters and exits doing nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_NULL_SPAN = _NullSpan()
+
+
 class NullTracer:
     """The tracing-off sentinel: every hook is a no-op and ``enabled`` is
     False, so guarded emit sites cost one attribute read.  Exporters refuse
@@ -159,6 +226,12 @@ class NullTracer:
 
     def emit(self, kind: str, **kw) -> None:
         pass
+
+    def span(self, kind: str, *, rid: int | None = None,
+             slot: int | None = None, **data) -> _NullSpan:
+        """The one shared no-op span: it yields None, so a site that adds
+        counts to the span's event guards them with ``if tracer.enabled``."""
+        return _NULL_SPAN
 
     def inc(self, name: str, value: float = 1.0) -> None:
         pass

@@ -387,9 +387,10 @@ class ServeEngine:
         and empty slots are inert, so a freed slot can be re-filled at any
         step without touching the others."""
         logits, new_state = self.model_decode.decode_step(params, tokens, state)
-        merged = jax.tree.map(
-            lambda ax, new, old: row_select(ax, new, old, active),
-            self._axes, new_state, state)
+        with jax.named_scope("select"):
+            merged = jax.tree.map(
+                lambda ax, new, old: row_select(ax, new, old, active),
+                self._axes, new_state, state)
         return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), merged
 
     def _masked_step_modal(self, params, tokens, state, active, modes):
@@ -402,9 +403,10 @@ class ServeEngine:
         with bind_modes(modes):
             logits, new_state = self.model_decode.decode_step(
                 params, tokens, state)
-        merged = jax.tree.map(
-            lambda ax, new, old: row_select(ax, new, old, active),
-            self._axes, new_state, state)
+        with jax.named_scope("select"):
+            merged = jax.tree.map(
+                lambda ax, new, old: row_select(ax, new, old, active),
+                self._axes, new_state, state)
         return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), merged
 
     def _probe_fn(self, params, tokens, state, active, cur, ref, down):
@@ -450,33 +452,48 @@ class ServeEngine:
         token, the request just continues), then run one masked batched
         decode step for every active slot.  Returns this step's
         (rid, token) events in emission order."""
+        tr = self.tracer
+        with tr.span("step") as ev:
+            events = self._step_body()
+            if tr.enabled:
+                ev.step = self.scheduler.clock
+                ev.data["n_active"] = int(self._active.sum())
+        return events
+
+    def _step_body(self) -> list[tuple[int, int]]:
+        tr = self.tracer
         events: list[tuple[int, int]] = []
-        self.scheduler.tick()
-        self.tracer.step = self.scheduler.clock
-        for victim in self.scheduler.plan_preemptions():
-            self._park_slot(victim, cause="priority")
-        self.layout.begin_admission()
-        for slot, ticket in self.scheduler.admit(can_admit=self._can_admit):
+        with tr.span("schedule") as ev:
+            self.scheduler.tick()
+            tr.step = self.scheduler.clock
+            if tr.enabled:
+                ev.step = tr.step
+            for victim in self.scheduler.plan_preemptions():
+                self._park_slot(victim, cause="priority")
+            self.layout.begin_admission()
+            admitted = self.scheduler.admit(can_admit=self._can_admit)
+        for slot, ticket in admitted:
             if slot < 0:
                 # zero-budget admission (nothing fits the cache): the
                 # scheduler completed it without a slot — route the
                 # completion through metrics so summary()["completed"]
                 # agrees with drain()/scheduler.completed
                 self.metrics.on_done(ticket.rid, step=self.scheduler.clock)
-                if self.tracer.enabled:
-                    self.tracer.emit("done", rid=ticket.rid, slot=-1,
-                                     cause="zero_budget")
+                if tr.enabled:
+                    tr.emit("done", rid=ticket.rid, slot=-1,
+                            cause="zero_budget")
                 continue
             if ticket.tokens:
                 self._resume_slot(slot, ticket)
                 continue
-            if self.tracer.enabled:
-                self.tracer.emit("admit", rid=ticket.rid, slot=slot,
-                                 tenant=ticket.tenant, rclass=ticket.rclass)
-            first = self._prefill_slot(slot, ticket)
-            self.metrics.on_first_token(ticket.rid)
-            events.append((ticket.rid, first))
-            self._emit(ticket, slot, first)
+            with tr.span("admit", rid=ticket.rid, slot=slot) as ev:
+                if tr.enabled:
+                    ev.data.update(tenant=ticket.tenant, rclass=ticket.rclass,
+                                   prompt_len=len(ticket.prompt))
+                first = self._prefill_slot(slot, ticket)
+                self.metrics.on_first_token(ticket.rid)
+                events.append((ticket.rid, first))
+                self._emit(ticket, slot, first)
         if self._active.any():
             self._prepare_pages()
         if self._active.any():
@@ -487,10 +504,11 @@ class ServeEngine:
             if (self.slo is not None
                     and self.metrics.decode_steps % self.adapt_every == 0
                     and self._active.any()):
-                if self._per_tenant_adapt:
-                    self._adapt_tick_tenants()
-                else:
-                    self._adapt_tick()
+                with tr.span("adapt"):
+                    if self._per_tenant_adapt:
+                        self._adapt_tick_tenants()
+                    else:
+                        self._adapt_tick()
             self._page_tick()
         return events
 
@@ -634,42 +652,51 @@ class ServeEngine:
         return cached, label
 
     def _decode_step(self) -> list[tuple[int, int]]:
-        """One masked batched decode step (the non-speculative path)."""
+        """One masked batched decode step (the non-speculative path):
+        host inputs, the dispatch, the sync on its tokens, then each row's
+        token bookkeeping, each phase a span of its own."""
+        tr = self.tracer
         events: list[tuple[int, int]] = []
-        tokens = jnp.asarray(self._last_tok[:, None])
-        active = jnp.asarray(self._active)
-        tenant_active = self._tenant_active()
-        t0 = time.perf_counter()
-        if self.slo is not None:
-            if self._per_tenant_adapt:
-                scalars, label = self._bound_scalars(tenant_active)
-            else:
-                scalars, label = self.mode_table.scalars(), self.mode_table.label()
-            next_tok, self.state = self._step_modal(
-                self.params, tokens, self.state, active, scalars)
-        else:
-            label = self._static_decode_label
-            next_tok, self.state = self._step(
-                self.params, tokens, self.state, active)
-        produced = np.asarray(next_tok)  # syncs the step
-        self._last_step_ms = (time.perf_counter() - t0) * 1e3
-        n_active = int(self._active.sum())
-        self.metrics.on_decode_step(
-            n_active, mode=label, tenant_active=tenant_active)
-        if self.tracer.enabled:
-            self.tracer.emit("decode_step", dur_ms=self._last_step_ms,
-                             n_active=n_active, mode=label)
-            self.tracer.set_gauge("active_slots", n_active)
-            self.profiler.record("decode", self._last_step_ms / 1e3,
-                                 tokens=n_active)
-            self.profiler.observe_cache("decode_step",
-                                        self.decode_compile_count)
-        for slot in np.nonzero(self._active)[0]:
-            ticket = self.scheduler.by_slot[int(slot)]
-            tok = int(produced[slot])
-            self._row_len[slot] += 1  # this step appended one KV entry
-            events.append((ticket.rid, tok))
-            self._emit(ticket, int(slot), tok)
+        with tr.span("decode") as ev:
+            with tr.span("decode.inputs"):
+                tokens = jnp.asarray(self._last_tok[:, None])
+                active = jnp.asarray(self._active)
+                tenant_active = self._tenant_active()
+                if self.slo is None:
+                    label = self._static_decode_label
+                elif self._per_tenant_adapt:
+                    scalars, label = self._bound_scalars(tenant_active)
+                else:
+                    scalars, label = (self.mode_table.scalars(),
+                                      self.mode_table.label())
+            t0 = time.perf_counter()
+            with tr.span("decode.dispatch"):
+                if self.slo is not None:
+                    next_tok, self.state = self._step_modal(
+                        self.params, tokens, self.state, active, scalars)
+                else:
+                    next_tok, self.state = self._step(
+                        self.params, tokens, self.state, active)
+            with tr.span("decode.sync"):
+                produced = np.asarray(next_tok)  # syncs the step
+            self._last_step_ms = (time.perf_counter() - t0) * 1e3
+            n_active = int(self._active.sum())
+            self.metrics.on_decode_step(
+                n_active, mode=label, tenant_active=tenant_active)
+            if tr.enabled:
+                ev.data.update(n_active=n_active, mode=label)
+                tr.set_gauge("active_slots", n_active)
+                self.profiler.record("decode", self._last_step_ms / 1e3,
+                                     tokens=n_active)
+                self.profiler.observe_cache("decode_step",
+                                            self.decode_compile_count)
+            with tr.span("emit"):
+                for slot in np.nonzero(self._active)[0]:
+                    ticket = self.scheduler.by_slot[int(slot)]
+                    tok = int(produced[slot])
+                    self._row_len[slot] += 1  # this step appended one KV entry
+                    events.append((ticket.rid, tok))
+                    self._emit(ticket, int(slot), tok)
         return events
 
     def _spec_step(self) -> list[tuple[int, int]]:
@@ -677,60 +704,68 @@ class ServeEngine:
         k+1 positions with the exact baseline step, emit each slot's
         accepted prefix plus the correction token (clamped to its remaining
         decode budget), and roll the state back inside the compiled round."""
+        tr = self.tracer
         events: list[tuple[int, int]] = []
-        active_np = self._active.copy()
-        tokens = jnp.asarray(self._last_tok[:, None])
-        active = jnp.asarray(active_np)
-        t0 = time.perf_counter()
-        drafts, greedy, n_acc, self.state = self._spec_round(
-            self.params, tokens, self.state, active,
-            self._spec_table.scalars_shifted(-self.draft_shift),
-            self._spec_table.scalars(),
-        )
-        drafts = np.asarray(drafts)  # (k, B)
-        greedy = np.asarray(greedy)  # (k+1, B)
-        n_acc = np.asarray(n_acc)  # (B,) — syncs the round
-        self._last_step_ms = (time.perf_counter() - t0) * 1e3
-        n_active = int(active_np.sum())
-        self.metrics.on_decode_step(
-            n_active,
-            mode=(self.mode_table.label() if self.mode_table is not None
-                  else self._static_decode_label),
-            tenant_active=self._tenant_active(),
-        )
-        accepted = agreed = emitted = 0
-        for slot in np.nonzero(active_np)[0]:
-            ticket = self.scheduler.by_slot[int(slot)]
-            j = int(n_acc[slot])
-            # the rolled-back cache holds the accepted prefix + correction
-            # (budget clamping truncates *emission*, not the cache)
-            self._row_len[slot] += j + 1
-            # two accounts: metrics credit only drafts that were *emitted*
-            # (a budget-truncated tail did no useful work), while the
-            # controller sees raw draft/verify *agreement* — truncation says
-            # nothing about draft quality and must not read as rejection
-            agreed += j
-            accepted += min(j, ticket.remaining)
-            burst = [int(drafts[i, slot]) for i in range(j)]
-            burst.append(int(greedy[j, slot]))  # correction / bonus token
-            for tok in burst[:ticket.remaining]:
-                events.append((ticket.rid, tok))
-                self._emit(ticket, int(slot), tok)
-                emitted += 1
-        self._last_step_tokens = emitted / n_active if n_active else 1.0
-        self.metrics.on_spec_round(
-            n_active, drafted=self.spec.k * n_active,
-            accepted=accepted, emitted=emitted)
-        if self.tracer.enabled:
-            from repro.spec.rollout import trace_round
+        with tr.span("spec_round") as ev:
+            with tr.span("decode.inputs"):
+                active_np = self._active.copy()
+                tokens = jnp.asarray(self._last_tok[:, None])
+                active = jnp.asarray(active_np)
+                draft_modes = self._spec_table.scalars_shifted(-self.draft_shift)
+                verify_modes = self._spec_table.scalars()
+            t0 = time.perf_counter()
+            with tr.span("decode.dispatch"):
+                drafts, greedy, n_acc, self.state = self._spec_round(
+                    self.params, tokens, self.state, active,
+                    draft_modes, verify_modes)
+            with tr.span("decode.sync"):
+                drafts = np.asarray(drafts)  # (k, B)
+                greedy = np.asarray(greedy)  # (k+1, B)
+                n_acc = np.asarray(n_acc)  # (B,) — syncs the round
+            self._last_step_ms = (time.perf_counter() - t0) * 1e3
+            n_active = int(active_np.sum())
+            self.metrics.on_decode_step(
+                n_active,
+                mode=(self.mode_table.label() if self.mode_table is not None
+                      else self._static_decode_label),
+                tenant_active=self._tenant_active(),
+            )
+            accepted = agreed = emitted = 0
+            with tr.span("emit"):
+                for slot in np.nonzero(active_np)[0]:
+                    ticket = self.scheduler.by_slot[int(slot)]
+                    j = int(n_acc[slot])
+                    # the rolled-back cache holds the accepted prefix +
+                    # correction (budget clamping truncates *emission*, not
+                    # the cache)
+                    self._row_len[slot] += j + 1
+                    # two accounts: metrics credit only drafts that were
+                    # *emitted* (a budget-truncated tail did no useful
+                    # work), while the controller sees raw draft/verify
+                    # *agreement* — truncation says nothing about draft
+                    # quality and must not read as rejection
+                    agreed += j
+                    accepted += min(j, ticket.remaining)
+                    burst = [int(drafts[i, slot]) for i in range(j)]
+                    burst.append(int(greedy[j, slot]))  # correction / bonus
+                    for tok in burst[:ticket.remaining]:
+                        events.append((ticket.rid, tok))
+                        self._emit(ticket, int(slot), tok)
+                        emitted += 1
+            self._last_step_tokens = emitted / n_active if n_active else 1.0
+            self.metrics.on_spec_round(
+                n_active, drafted=self.spec.k * n_active,
+                accepted=accepted, emitted=emitted)
+            if tr.enabled:
+                from repro.spec.rollout import trace_round
 
-            trace_round(self.tracer, k=self.spec.k, n_active=n_active,
-                        agreed=agreed, emitted=emitted,
-                        dur_ms=self._last_step_ms)
-            self.tracer.set_gauge("active_slots", n_active)
-            self.profiler.record("spec", self._last_step_ms / 1e3,
-                                 tokens=emitted)
-            self.profiler.observe_cache("spec_round", self.spec_compile_count)
+                trace_round(tr, ev, k=self.spec.k, n_active=n_active,
+                            agreed=agreed, emitted=emitted)
+                tr.set_gauge("active_slots", n_active)
+                self.profiler.record("spec", self._last_step_ms / 1e3,
+                                     tokens=emitted)
+                self.profiler.observe_cache("spec_round",
+                                            self.spec_compile_count)
         self._spec_window[0] += self.spec.k * n_active
         self._spec_window[1] += agreed
         if (self._accept_ctrl is not None
@@ -856,20 +891,24 @@ class ServeEngine:
     # -- internals -----------------------------------------------------------
 
     def _prefill_slot(self, slot: int, ticket: Ticket) -> int:
-        t0 = time.perf_counter()
-        logits, solo = self._prefill(
-            self.params, jnp.asarray(ticket.prompt)[None, :], self._solo0)
-        self.state = self.layout.scatter_row(
-            self.state, solo, slot, prompt=ticket.prompt)
-        self._row_len[slot] = len(ticket.prompt)
-        first = int(jnp.argmax(logits[0, -1]))  # syncs the prefill
-        if self.tracer.enabled:
-            dur_s = time.perf_counter() - t0
-            self.tracer.emit("prefill", rid=ticket.rid, slot=slot,
-                             dur_ms=dur_s * 1e3,
-                             prompt_len=len(ticket.prompt))
-            self.profiler.record("prefill", dur_s,
-                                 tokens=len(ticket.prompt))
+        """Solo prefill at the prompt's true length, its row written into
+        the slot, then the first-token sync (the ``prefill`` span).  The
+        row's ``scatter`` is dispatched before the sync, so the sync also
+        waits for it and the next step's dispatch finds the old state
+        freed: the scatter is a span inside the prefill."""
+        tr = self.tracer
+        n = len(ticket.prompt)
+        with tr.span("prefill", rid=ticket.rid, slot=slot) as ev:
+            logits, solo = self._prefill(
+                self.params, jnp.asarray(ticket.prompt)[None, :], self._solo0)
+            with tr.span("scatter", rid=ticket.rid, slot=slot):
+                self.state = self.layout.scatter_row(
+                    self.state, solo, slot, prompt=ticket.prompt)
+            first = int(jnp.argmax(logits[0, -1]))  # syncs the prefill
+        self._row_len[slot] = n
+        if tr.enabled:
+            ev.data["prompt_len"] = n
+            self.profiler.record("prefill", ev.data["dur_ms"] / 1e3, tokens=n)
             cache_size = getattr(self._prefill, "_cache_size", None)
             self.profiler.observe_cache(
                 "prefill", cache_size() if callable(cache_size) else None)

@@ -215,9 +215,10 @@ def build_spec_round(model, axes, k: int, modal_verify: bool):
 REJECT_STORM_RATE = 0.25
 
 
-def trace_round(tracer, *, k: int, n_active: int, agreed: int, emitted: int,
-                dur_ms: float | None = None) -> None:
-    """Emit one speculative round's trace record (repro.obs).
+def trace_round(tracer, event, *, k: int, n_active: int, agreed: int,
+                emitted: int) -> None:
+    """Record one speculative round's counts on its ``spec_round`` span
+    ``event`` (repro.obs).
 
     ``agreed`` is raw draft/verify agreement (what the acceptance controller
     sees), ``emitted`` the tokens that actually left the engine after budget
@@ -228,12 +229,10 @@ def trace_round(tracer, *, k: int, n_active: int, agreed: int, emitted: int,
         return
     drafted = k * n_active
     rate = agreed / drafted if drafted else None
-    tracer.emit(
-        "spec_round",
-        cause=("reject_storm" if rate is not None and rate < REJECT_STORM_RATE
-               else None),
-        dur_ms=dur_ms, n_active=n_active, drafted=drafted, agreed=agreed,
-        emitted=emitted, accept_rate=rate)
+    if rate is not None and rate < REJECT_STORM_RATE:
+        event.cause = "reject_storm"
+    event.data.update(n_active=n_active, drafted=drafted, agreed=agreed,
+                      emitted=emitted, accept_rate=rate)
     tracer.inc("spec_rounds")
     tracer.inc("spec_drafted", drafted)
     tracer.inc("spec_agreed", agreed)

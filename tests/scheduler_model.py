@@ -440,7 +440,7 @@ def check_all(sched, log) -> None:
 # ---------------------------------------------------------------------------
 
 #: repro.obs event kinds -> drive() log kinds.  Everything else in the
-#: trace (decode_step, mode_switch, cow_fork, ...) is engine detail the
+#: trace (engine spans, mode_switch, cow_fork, ...) is engine detail the
 #: scheduler contract does not speak about and is dropped by the mapping.
 TRACE_KINDS = {
     "submit": SUBMIT,

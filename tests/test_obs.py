@@ -163,7 +163,7 @@ class TestExport:
 class TestTimeline:
     def test_merges_three_precision_axes(self):
         rows = precision_timeline([
-            Event(0.0, 1, "decode_step", data={"mode": "M16", "n_active": 2}),
+            Event(0.0, 1, "decode", data={"mode": "M16", "n_active": 2}),
             Event(1.0, 4, "mode_switch", data={"mode": "M24",
                                                "sites": {"mlp": "M24"}}),
             Event(2.0, 6, "draft_shift", data={"shift": 1}),
@@ -382,3 +382,166 @@ class TestEngineTracing:
         assert len(full) == 2
         filtered = log_from_trace(evs, skip_causes=("page_pressure",))
         assert [rid for _, _, rid, _ in filtered] == [1]
+
+
+class TestSpans:
+    def test_spans_nest_carry_duration_parent_and_rid_in_order(self):
+        t = iter(float(i) for i in range(100))
+        tr = Tracer(clock=lambda: next(t))
+        with tr.span("step") as step:
+            with tr.span("admit", rid=7, slot=1, prompt_len=5) as admit:
+                with tr.span("prefill", rid=7, slot=1):
+                    pass
+            tr.emit("token", rid=7)
+            with tr.span("decode") as dec:
+                dec.data["n_active"] = 3
+        kinds = [e.kind for e in tr.events]
+        assert kinds == ["step", "admit", "prefill", "token", "decode"]
+        ev = {e.kind: e for e in tr.events}
+        assert ev["step"] is step and ev["admit"] is admit
+        assert [e.ts for e in tr.events] == sorted(e.ts for e in tr.events)
+        assert ev["step"].data["parent"] is None
+        assert ev["admit"].data["parent"] == "step"
+        assert ev["prefill"].data["parent"] == "admit"
+        assert ev["decode"].data["parent"] == "step"
+        assert (ev["prefill"].rid, ev["prefill"].slot) == (7, 1)
+        assert ev["admit"].data["prompt_len"] == 5
+        assert ev["decode"].data["n_active"] == 3
+        # clock reads: step 0, admit 1, prefill 2..3, admit ends 4, token 5,
+        # decode 6..7, step ends 8
+        assert ev["prefill"].data["dur_ms"] == 1e3
+        assert ev["admit"].data["dur_ms"] == 3e3
+        assert ev["step"].data["dur_ms"] == 8e3
+        assert tr.emitted == len(tr.events) == 5 and tr._open == []
+
+    def test_span_closes_on_exception(self):
+        tr = Tracer(clock=lambda: 0.0)
+        with pytest.raises(ValueError):
+            with tr.span("step"):
+                raise ValueError("boom")
+        assert tr._open == [] and tr.events[0].data["dur_ms"] == 0.0
+
+    def test_null_span_is_one_shared_no_op(self):
+        a = NULL_TRACER.span("step")
+        b = NULL_TRACER.span("admit", rid=1, slot=2, prompt_len=4)
+        assert a is b
+        with a as ev:
+            assert ev is None
+        assert NULL_TRACER.events == () and NULL_TRACER.emitted == 0
+
+    def test_chrome_renders_every_span_nested(self):
+        t = iter(float(i) for i in range(100))
+        tr = Tracer(clock=lambda: next(t))
+        with tr.span("step"):
+            with tr.span("decode"):
+                with tr.span("decode.sync"):
+                    pass
+            with tr.span("adapt"):
+                pass
+        doc = tr.chrome()
+        assert validate_chrome(doc) == []
+        spans = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
+        assert set(spans) == {"step", "decode", "decode.sync", "adapt"}
+        assert spans["step"]["ts"] == 0.0 and spans["step"]["dur"] == 7e6
+        assert spans["decode.sync"]["ts"] == 2e6
+        assert spans["decode.sync"]["args"]["parent"] == "decode"
+
+    @pytest.mark.parametrize("path", ["plain", "spec", "adapt"])
+    def test_span_sites_keep_tokens_and_compiles(self, path):
+        from repro.adapt import SLO
+        from repro.serve import AdaptConfig
+        from repro.spec import SpecConfig
+
+        cfg, model, params = _tiny()
+        reqs = _reqs(cfg, 4, max_new=6)
+        extra = {"plain": {}, "spec": {"spec": SpecConfig(k=2, draft_shift=1)},
+                 "adapt": {"adapt": AdaptConfig(slo=SLO(max_err=0.5),
+                                                adapt_every=2)}}[path]
+        base = ServeConfig(batch_slots=2, max_len=32, **extra)
+        e_off = ServeEngine(model, params, config=base)
+        e_on = ServeEngine(model, params, config=dataclasses.replace(
+            base, trace=TraceConfig()))
+        assert e_off.generate_batch(reqs) == e_on.generate_batch(reqs)
+        assert e_off.decode_compile_count == e_on.decode_compile_count
+        assert e_off.spec_compile_count == e_on.spec_compile_count
+        events = list(e_on.tracer.events)
+        kinds = {e.kind for e in events}
+        round_kind = "spec_round" if path == "spec" else "decode"
+        assert {"step", "schedule", "admit", "prefill", "scatter", round_kind,
+                "decode.inputs", "decode.dispatch", "decode.sync",
+                "emit"} <= kinds
+        if path == "adapt":
+            assert "adapt" in kinds
+        parents = {e.kind: e.data["parent"] for e in events
+                   if e.data and "dur_ms" in e.data}
+        assert parents["step"] is None
+        assert parents["schedule"] == parents["admit"] == "step"
+        assert parents["prefill"] == "admit"
+        assert parents["scatter"] == "prefill"
+        assert parents[round_kind] == "step"
+        for kid in ("decode.inputs", "decode.dispatch", "decode.sync", "emit"):
+            assert parents[kid] == round_kind
+        assert all(e.data["dur_ms"] >= 0 for e in events
+                   if e.data and "dur_ms" in e.data)
+        assert validate_chrome(e_on.tracer.chrome()) == []
+        check_replay(e_on)
+
+    def test_admit_events_are_the_fresh_admissions_in_order(self):
+        cfg, model, params = _tiny()
+        eng = ServeEngine(model, params, config=ServeConfig(
+            batch_slots=2, max_len=24, trace=TraceConfig()))
+        admitted = []
+        admit = eng.scheduler.admit
+
+        def recording(**kw):
+            out = admit(**kw)
+            admitted.extend((eng.scheduler.clock, t.rid, slot) for slot, t in out
+                            if slot >= 0 and not t.tokens)
+            return out
+        eng.scheduler.admit = recording
+        eng.generate_batch(_reqs(cfg, 5))
+        events = list(eng.tracer.events)
+        admits = [e for e in events if e.kind == "admit"]
+        assert [(e.step, e.rid, e.slot) for e in admits] == admitted
+        # the admit event opens the admission: its prefill starts after it
+        prefill = {e.rid: e for e in events if e.kind == "prefill"}
+        for e in admits:
+            assert e.ts <= prefill[e.rid].ts
+            assert e.data["prompt_len"] == 6 and e.data["parent"] == "step"
+        log = check_replay(eng)
+        assert sum(1 for _, k, _, _ in log if k == "admit") == 5
+
+    def test_prefill_profile_takes_the_span_duration(self):
+        cfg, model, params = _tiny()
+        eng = ServeEngine(model, params, config=ServeConfig(
+            batch_slots=2, max_len=24, trace=TraceConfig()))
+        eng.generate_batch(_reqs(cfg, 3))
+        spans = [e.data["dur_ms"] for e in eng.tracer.events
+                 if e.kind == "prefill"]
+        st = eng.profiler.phases["prefill"]
+        assert st.calls == len(spans) == 3
+        assert st.wall_s == pytest.approx(sum(spans) / 1e3, rel=1e-12)
+
+    def test_spans_reach_the_profiler(self, tmp_path):
+        cfg, model, params = _tiny()
+        eng = ServeEngine(model, params, config=ServeConfig(
+            batch_slots=2, max_len=24, trace=TraceConfig()))
+        reqs = _reqs(cfg, 2, max_new=3)
+        eng.generate_batch(reqs[:1])  # compile outside the profile
+        before = len(eng.tracer.events)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.generate_batch(reqs[1:])
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        pd = jax.profiler.ProfileData.from_file(str(path))
+        names = [e.name for plane in pd.planes if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name.startswith("serve.")]
+        assert {"serve.step", "serve.schedule", "serve.admit", "serve.prefill",
+                "serve.scatter", "serve.decode", "serve.decode.inputs",
+                "serve.decode.dispatch", "serve.decode.sync",
+                "serve.emit"} <= set(names)
+        steps = [e for e in list(eng.tracer.events)[before:] if e.kind == "step"]
+        assert names.count("serve.step") == len(steps) > 0
