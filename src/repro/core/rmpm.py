@@ -28,6 +28,7 @@ achievable precision near 2^-24 for large K (see DESIGN.md section 2 / tests).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Sequence
 
@@ -69,6 +70,64 @@ def _neumaier_sum(parts: Sequence[Array]) -> Array:
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass
+class OneLimbStats:
+    """Trace-time count of contraction sites that took the one-limb path:
+    ``einsums_saved`` is einsum dispatches not emitted (k(k+1)/2 - 1 per
+    site), ``passes_saved`` the partial products whose limb is zero and
+    which were therefore never multiplied."""
+
+    sites: int = 0
+    einsums_saved: int = 0
+    passes_saved: int = 0
+
+    def since(self, snap: "OneLimbStats") -> "OneLimbStats":
+        return OneLimbStats(*(getattr(self, f.name) - getattr(snap, f.name)
+                              for f in dataclasses.fields(self)))
+
+
+_ONE_LIMB = OneLimbStats()
+
+
+def one_limb_stats() -> OneLimbStats:
+    """The running one-limb count (copy it to take a snapshot)."""
+    return _ONE_LIMB
+
+
+def _is_bf16(x) -> bool:
+    return not isinstance(x, DoubleF32) and x.dtype == jnp.bfloat16
+
+
+def _one_limb_einsum(eq: str, a, b, k: int, rounding: str) -> Array:
+    """The k-limb contraction when an operand is ``bfloat16``: that operand
+    is its own single exact limb (its split is ``[x, 0, ...]``), so only the
+    other operand is split, its limbs stacked on a fresh leading index and
+    contracted in ONE einsum, and the partial results summed highest limb
+    first — the order the retained terms with a nonzero limb have in
+    ``_limb_einsum``.  Both operands bf16: one plain pass."""
+    n_terms = len(limb_lib.limb_product_terms(k))
+    _ONE_LIMB.sites += 1
+    _ONE_LIMB.einsums_saved += n_terms - 1
+    if _is_bf16(a) and _is_bf16(b):
+        _ONE_LIMB.passes_saved += n_terms - 1
+        return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+    _ONE_LIMB.passes_saved += n_terms - k
+    z = next(c for c in "zyxwvutsrqponmlkjihgfedcba" if c not in eq)
+    lhs, out = eq.split("->")
+    ea, eb = lhs.split(",")
+    if _is_bf16(b):
+        stacked_eq = f"{z}{ea},{eb}->{z}{out}"
+        a = limb_lib.split_limbs(a, k, rounding)
+    else:
+        stacked_eq = f"{ea},{z}{eb}->{z}{out}"
+        b = limb_lib.split_limbs(b, k, rounding)
+    parts = jnp.einsum(stacked_eq, a, b, preferred_element_type=jnp.float32)
+    acc = parts[k - 1]
+    for i in range(k - 2, -1, -1):
+        acc = acc + parts[i]
+    return acc
+
+
 def _limb_einsum(
     eq: str,
     a,
@@ -78,9 +137,13 @@ def _limb_einsum(
     compensated: bool | None = None,
 ) -> Array:
     """k-limb multi-pass contraction: sum over Karatsuba terms (i+j < k) of
-    einsum(a_i, b_j), bf16 x bf16 -> f32 per pass."""
+    einsum(a_i, b_j), bf16 x bf16 -> f32 per pass.  A ``bfloat16`` operand
+    takes the one-limb path (:func:`_one_limb_einsum`) on the plain f32
+    ladder; the compensated modes keep the full split."""
     if compensated is None:
         compensated = k >= 4
+    if not compensated and (_is_bf16(a) or _is_bf16(b)):
+        return _one_limb_einsum(eq, a, b, k, rounding)
     a_limbs = limb_lib.split_limbs(a, k, rounding)
     b_limbs = limb_lib.split_limbs(b, k, rounding)
     terms = limb_lib.limb_product_terms(k)

@@ -467,8 +467,11 @@ def kv_view(cache: KVCache | PagedKVCache):
 
 
 def _dequant_chunk(x: Array, scale: Array | None) -> Array:
+    """A cache chunk as the contraction reads it: an unscaled chunk keeps its
+    storage dtype (a bf16 value is one exact limb, so the limb engine need
+    not split it); an int8 chunk times its scale is an f32 value."""
     if scale is None:
-        return x.astype(jnp.float32)
+        return x
     return x.astype(jnp.float32) * scale
 
 

@@ -38,12 +38,14 @@ Speculative decoding).
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.rmpm import one_limb_stats
 from repro.models.lm import LanguageModel
 from repro.obs import NULL_TRACER, PhaseProfiler, TraceConfig, Tracer
 from repro.serve.config import ServeConfig
@@ -264,6 +266,8 @@ class ServeEngine:
         self._axes = self.layout.axes
         self._prefill = jax.jit(self.model_prefill.decode_step)
         self._step = jax.jit(self._masked_step)
+        #: one-limb sites of the last decode program built (_decode_body)
+        self.decode_one_limb = None
         # host-side slot mirrors
         self._active = np.zeros((batch_slots,), bool)
         self._last_tok = np.zeros((batch_slots,), np.int32)
@@ -381,12 +385,23 @@ class ServeEngine:
 
     # -- compiled pieces -----------------------------------------------------
 
+    def _decode_body(self, params, tokens, state):
+        """The model's decode step, traced once per built program: the
+        one-limb contraction sites it traced (``repro.core.rmpm``) are kept
+        as ``decode_one_limb`` and set as ``one_limb_*`` tracer gauges."""
+        snap = dataclasses.replace(one_limb_stats())
+        out = self.model_decode.decode_step(params, tokens, state)
+        self.decode_one_limb = one_limb_stats().since(snap)
+        for name, value in dataclasses.asdict(self.decode_one_limb).items():
+            self.tracer.set_gauge(f"one_limb_{name}", value)
+        return out
+
     def _masked_step(self, params, tokens, state, active):
         """One decode token for every slot; rows where ``active`` is False
         keep their exact prior state (cache, positions, lengths) — finished
         and empty slots are inert, so a freed slot can be re-filled at any
         step without touching the others."""
-        logits, new_state = self.model_decode.decode_step(params, tokens, state)
+        logits, new_state = self._decode_body(params, tokens, state)
         with jax.named_scope("select"):
             merged = jax.tree.map(
                 lambda ax, new, old: row_select(ax, new, old, active),
@@ -401,8 +416,7 @@ class ServeEngine:
         from repro.adapt import bind_modes
 
         with bind_modes(modes):
-            logits, new_state = self.model_decode.decode_step(
-                params, tokens, state)
+            logits, new_state = self._decode_body(params, tokens, state)
         with jax.named_scope("select"):
             merged = jax.tree.map(
                 lambda ax, new, old: row_select(ax, new, old, active),

@@ -28,13 +28,16 @@ it does not re-prefill), so ``on_first_token`` ignores repeats.
 
 Plan-cache numbers are deltas against the engine-construction snapshot, so
 they count only the planning this engine triggered (``repro.plan``
-caches globally).
+caches globally); so are the one-limb numbers, the contraction sites that
+the programs this engine traced ran with a bf16 operand as its one limb
+(``repro.core.rmpm.one_limb_stats``).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
 
+from repro.core.rmpm import one_limb_stats
 from repro.plan import plan_cache_stats
 
 
@@ -103,6 +106,7 @@ class ServeMetrics:
         self._t_last_event: float | None = None
         snap = plan_cache_stats()
         self._plan_snap = (snap.hits, snap.misses)
+        self._one_limb_snap = dataclasses.replace(one_limb_stats())
 
     # -- hooks (called by ServeEngine) --------------------------------------
 
@@ -331,6 +335,11 @@ class ServeMetrics:
             "entries": snap.entries,
         }
 
+    def one_limb_delta(self) -> dict:
+        """One-limb contraction sites traced since construction
+        (``repro.core.rmpm.one_limb_stats``)."""
+        return dataclasses.asdict(one_limb_stats().since(self._one_limb_snap))
+
     def summary(self) -> dict:
         ttfts = [self.ttft(r) for r in self.requests if self.ttft(r) is not None]
         lats = [self.latency(r) for r in self.requests if self.latency(r) is not None]
@@ -368,6 +377,7 @@ class ServeMetrics:
             "peak_active": self.peak_active,
             "pages": self._pages_summary(),
             "plan_cache": self.plan_cache_delta(),
+            "one_limb": self.one_limb_delta(),
         }
 
     def _pages_summary(self) -> dict | None:

@@ -6,6 +6,8 @@ Validates the paper's central claims at the numeric level:
   * auto-mode picks cheap modes for integer-valued data (Fig 7)
   * GRTE rounding (Eq. 10) behaves between truncation and RNE
 """
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -14,6 +16,8 @@ import jax.numpy as jnp
 from hypothesis_compat import given, settings, st  # property tests skip w/o hypothesis
 
 from repro.core import (
+    MODE_LIMBS,
+    MODE_PASSES,
     DoubleF32,
     Mode,
     auto_mode,
@@ -26,6 +30,7 @@ from repro.core import (
     quantize_mantissa,
 )
 from repro.core import limb as limb_lib
+from repro.core.rmpm import one_limb_stats
 
 F32_LADDER = (Mode.M8, Mode.M16, Mode.M24)
 
@@ -144,6 +149,87 @@ class TestModeLadder:
         assert out.shape == (4, 6, 24)
         ref = np.asarray(jnp.einsum("bsk,kn->bsn", a, b))
         np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
+
+
+class TestOneLimbOperand:
+    """A bf16 operand is its own single exact limb: the engine splits only
+    the other operand and contracts its stacked limbs in one pass."""
+
+    #: (equation, shape of a, shape of b): the plain matmul and the two
+    #: decode attention contractions (q x K cache, probabilities x V cache)
+    EQS = [
+        ("mk,kn->mn", (24, 64), (64, 16)),
+        ("bqhgd,bkhd->bhgqk", (3, 1, 2, 2, 32), (3, 48, 2, 32)),
+        ("bhgqk,bkhd->bhgqd", (3, 2, 2, 1, 48), (3, 48, 2, 32)),
+    ]
+
+    @staticmethod
+    def _dots(fn, *args):
+        eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+        return ([e for e in eqns if e.primitive.name == "dot_general"],
+                [e for e in eqns if e.primitive.name == "bitcast_convert_type"])
+
+    @pytest.mark.parametrize("k", [1, 2, 3, 4, 6])
+    @pytest.mark.parametrize("eq,sa,sb", EQS)
+    def test_bf16_split_is_itself_then_zeros(self, rng, eq, sa, sb, k):
+        x = _rand(rng, *sb).astype(jnp.bfloat16)
+        limbs = limb_lib.split_limbs(x, k)
+        assert np.array_equal(np.asarray(limbs[0]).view(np.uint16),
+                              np.asarray(x).view(np.uint16))
+        assert not np.asarray(limbs[1:]).view(np.uint16).any()  # +0 only
+
+    @pytest.mark.parametrize("side", ["a", "b"])
+    @pytest.mark.parametrize("mode", F32_LADDER)
+    @pytest.mark.parametrize("eq,sa,sb", EQS)
+    def test_matches_f32_upcast(self, rng, eq, sa, sb, mode, side):
+        a, b = _rand(rng, *sa), _rand(rng, *sb)
+        if side == "a":
+            a = a.astype(jnp.bfloat16)
+        else:
+            b = b.astype(jnp.bfloat16)
+        out = mp_einsum(eq, a, b, mode)
+        ref = mp_einsum(eq, a.astype(jnp.float32), b.astype(jnp.float32), mode)
+        err = float(jnp.max(jnp.abs(out - ref)) / jnp.max(jnp.abs(ref)))
+        assert err <= 2**-21
+        dots, _ = self._dots(lambda x, y: mp_einsum(eq, x, y, mode), a, b)
+        assert len(dots) == 1  # the other operand's limbs, stacked
+
+    @pytest.mark.parametrize("mode", F32_LADDER)
+    @pytest.mark.parametrize("eq,sa,sb", EQS)
+    def test_bf16_times_bf16_is_one_plain_pass(self, rng, eq, sa, sb, mode):
+        a = _rand(rng, *sa).astype(jnp.bfloat16)
+        b = _rand(rng, *sb).astype(jnp.bfloat16)
+        dots, bitcasts = self._dots(lambda x, y: mp_einsum(eq, x, y, mode), a, b)
+        assert len(dots) == 1 and not bitcasts  # no split at all
+        ref = mp_einsum(eq, a.astype(jnp.float32), b.astype(jnp.float32), mode)
+        np.testing.assert_array_equal(np.asarray(mp_einsum(eq, a, b, mode)),
+                                      np.asarray(ref))
+
+    @pytest.mark.parametrize("mode", F32_LADDER)
+    def test_counts_sites_and_saved_passes(self, rng, mode):
+        eq, sa, sb = self.EQS[1]
+        a, b = _rand(rng, *sa), _rand(rng, *sb).astype(jnp.bfloat16)
+        k = MODE_LIMBS[mode]
+        snap = dataclasses.replace(one_limb_stats())
+        jax.make_jaxpr(lambda x, y: mp_einsum(eq, x, y, mode))(a, b)
+        got = one_limb_stats().since(snap)
+        n_terms = k * (k + 1) // 2
+        assert (got.sites, got.einsums_saved, got.passes_saved) == (
+            1, n_terms - 1, n_terms - k)
+
+    @pytest.mark.parametrize("mode", [Mode.M32, Mode.M48])
+    @pytest.mark.parametrize("eq,sa,sb", EQS[1:])
+    def test_compensated_modes_keep_the_full_split(self, rng, eq, sa, sb, mode):
+        hi = _rand(rng, *sa)
+        a = DoubleF32(hi, hi * np.float32(2**-26))
+        b = _rand(rng, *sb).astype(jnp.bfloat16)
+        snap = dataclasses.replace(one_limb_stats())
+        out = mp_einsum(eq, a, b, mode)
+        ref = mp_einsum(eq, a, b.astype(jnp.float32), mode)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        dots, _ = self._dots(lambda y: mp_einsum(eq, a, y, mode), b)
+        assert len(dots) == MODE_PASSES[mode]
+        assert one_limb_stats().since(snap).sites == 0
 
 
 class TestRuntimeReconfiguration:

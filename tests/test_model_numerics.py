@@ -1,13 +1,16 @@
 """Numerical correctness of the model building blocks against naive
 references: flash attention vs dense softmax, SSD chunked vs sequential
 recurrence, RG-LRU associative scan vs step loop, MoE routing invariants."""
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
-from repro.core.policy import NATIVE_F32
+from repro.core.policy import NATIVE_F32, PAPER_BASELINE
+from repro.models import layers
 from repro.models.layers import flash_attention
 from repro.models import griffin as griffin_lib
 from repro.models import moe as moe_lib
@@ -66,6 +69,116 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-5)
 
 
+def _walk_scoped(jaxpr, stack=""):
+    """Every equation of a jaxpr and its sub-jaxprs, with the name-scope
+    stack it runs under."""
+    from jax.extend import core as jcore
+
+    for e in jaxpr.eqns:
+        ns = f"{stack}/{e.source_info.name_stack}"
+        yield e, ns
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield from _walk_scoped(sub.jaxpr, ns)
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield from _walk_scoped(sub, ns)
+
+
+def _tiny_lm(arch, policy=PAPER_BASELINE):
+    from repro.models import build_model
+
+    cfg = dataclasses.replace(get_smoke_config(arch).with_policy(policy),
+                              n_layers=2)
+    return cfg, build_model(cfg)
+
+
+class TestDecodeOverCache:
+    """Decode attention reads a bf16 cache as the one exact limb it is
+    (core.rmpm one-limb rule); the int8 cache keeps its f32 dequant path."""
+
+    B, CAP, HKV, G, HD, FILLED = 2, 24, 2, 2, 16, 13
+
+    def _filled(self, kind, rng):
+        b, cap, hkv, hd = self.B, self.CAP, self.HKV, self.HD
+        if kind == "paged":
+            cache = layers.paged_cache_init(b, cap, hkv, hd, "bfloat16",
+                                            n_pages=12, page_size=4)
+            cache = dataclasses.replace(
+                cache, page_tbl=jnp.arange(1, 13, dtype=jnp.int32).reshape(b, 6))
+        else:
+            dtype = "int8" if kind == "int8" else "bfloat16"
+            cache = layers.kv_cache_init(b, cap, hkv, hd, dtype, per_slot=True)
+        kv = [jnp.asarray(rng.standard_normal((b, self.FILLED, hkv, hd))
+                          .astype(np.float32)) for _ in range(2)]
+        return layers.kv_append(cache, *kv)
+
+    @pytest.mark.parametrize("kind", ["dense", "paged", "int8"])
+    def test_matches_f32_upcast_path(self, rng, kind):
+        cache = self._filled(kind, rng)
+        k, v, ks, vs = layers.kv_view(cache)
+        assert k.dtype == (jnp.int8 if kind == "int8" else jnp.bfloat16)
+        q = jnp.asarray(rng.standard_normal(
+            (self.B, 1, self.HKV * self.G, self.HD)).astype(np.float32))
+        at = dict(q_offset=cache.length - 1, kv_positions=cache.pos, chunk=8)
+        out = flash_attention(q, k, v, PAPER_BASELINE, k_scale=ks, v_scale=vs,
+                              **at)
+
+        def upcast(x, scale):  # the parent's chunk read, done up front
+            return x.astype(jnp.float32) * (1 if scale is None else scale)
+
+        ref = flash_attention(q, upcast(k, ks), upcast(v, vs), PAPER_BASELINE,
+                              **at)
+        if kind == "int8":
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        else:
+            err = float(jnp.max(jnp.abs(out - ref)))
+            assert err <= 2**-21 * float(jnp.max(jnp.abs(ref)))
+
+    @pytest.mark.parametrize("read", ["storage_dtype", "f32_upcast"])
+    def test_no_limb_split_of_the_cache(self, monkeypatch, read):
+        if read == "f32_upcast":  # control: the parent's read, which splits
+            monkeypatch.setattr(layers, "_dequant_chunk", lambda x, s: (
+                x.astype(jnp.float32) if s is None
+                else x.astype(jnp.float32) * s))
+        cfg, model = _tiny_lm("qwen1.5-0.5b")
+        b, max_len = 2, 48
+        params = jax.eval_shape(model.init, jax.random.key(0))
+        state = jax.eval_shape(
+            lambda: model.init_decode_state(b, max_len, per_slot=True))
+        tokens = jax.ShapeDtypeStruct((b, 1), jnp.int32)
+        jaxpr = jax.make_jaxpr(model.decode_step)(params, tokens, state).jaxpr
+        chunk_shape = (b, min(cfg.attn_chunk, max_len), cfg.n_kv_heads,
+                       cfg.head_dim)
+        splits = [e for e, ns in _walk_scoped(jaxpr)
+                  if e.primitive.name == "bitcast_convert_type"
+                  and "attention" in ns
+                  and e.invars[0].aval.dtype == jnp.float32
+                  and e.invars[0].aval.shape == chunk_shape]
+        if read == "f32_upcast":
+            assert splits  # the scan finds the split where there is one
+        else:
+            assert not splits
+
+    @pytest.mark.parametrize("arch,sites", [("qwen1.5-0.5b", 2),
+                                            ("mamba2-2.7b", 0)])
+    def test_one_limb_sites_of_the_decode_program(self, arch, sites):
+        from repro.serve import Request, ServeConfig, ServeEngine
+
+        _, model = _tiny_lm(arch)
+        eng = ServeEngine(model, model.init(jax.random.key(0)),
+                          config=ServeConfig(batch_slots=2, max_len=32,
+                                             trace=True))
+        eng.submit(Request(prompt=np.arange(1, 6, dtype=np.int32), max_new=3))
+        eng.drain()
+        got = eng.decode_one_limb
+        assert got.sites == sites
+        assert got.einsums_saved == 5 * sites  # 6 M24 einsums -> 1
+        assert eng.tracer.gauges["one_limb_sites"] == sites
+        # prefill attends over fresh f32 K/V: no site of its own
+        assert eng.metrics.summary()["one_limb"]["sites"] == sites
+
+
 class TestSSD:
     def test_chunked_matches_sequential(self, rng):
         cfg = get_smoke_config("mamba2-2.7b").with_policy(POLICY)
@@ -75,8 +188,6 @@ class TestSSD:
         a = -jnp.exp(jnp.asarray(rng.standard_normal(h).astype(np.float32)))
         bm = jnp.asarray(rng.standard_normal((b, s, n)).astype(np.float32))
         cm = jnp.asarray(rng.standard_normal((b, s, n)).astype(np.float32))
-        import dataclasses
-
         cfg = dataclasses.replace(cfg, ssm_chunk=8)
         y_chunk, final = ssm_lib._ssd_chunked(xh, dt, a, bm, cm, cfg)
         # sequential recurrence reference
