@@ -48,6 +48,21 @@ class Drive:
     t_close: float
     t_end: float  # loop stop
 
+    def ttft(self) -> tuple[list, int]:
+        """Seconds from due to first token of every request due in the
+        window, and how many got none: such a request failed, and counts
+        at the time it had waited when the loop stopped."""
+        waits, failed = [], 0
+        for r in self.records.values():
+            if r.arrival.segment != WINDOW or r.due_t is None:
+                continue
+            if r.token_t:
+                waits.append(r.token_t[0] - r.due_t)
+            else:
+                failed += 1
+                waits.append(self.t_end - r.due_t)
+        return waits, failed
+
 
 def no_annotation(_name):
     return contextlib.nullcontext()

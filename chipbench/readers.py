@@ -23,16 +23,16 @@ class Context:
     trace_offset_ns: float  # trace ns = host perf_counter s * 1e9 + offset
 
     @classmethod
-    def build(cls, d, engine, cfg, prof, peaks) -> "Context":
+    def build(cls, d, engine, dims: Dims, prof, peaks) -> "Context":
         admits = {e.rid: e.ts for e in engine.tracer.events if e.kind == "admit"}
-        ops = Dims.of(cfg).gemms()
+        ops = dims.gemms()
         policy = engine.model_decode.cfg.policy
         plans = engine.phase_plans.get("decode", {})
         mode = {op: policy.mode_for(op).name for op in ops}
         impl = {op: (plans[op].impl if op in plans else policy.impl) for op in ops}
         trace = TraceData.from_dir(prof.dir)
         offset = trace.window[0] - prof.t0 * 1e9
-        return cls(d, admits, Dims.of(cfg), peaks, engine.slots, mode, impl, trace, offset)
+        return cls(d, admits, dims, peaks, engine.slots, mode, impl, trace, offset)
 
     def to_trace(self, t: float) -> int:
         return int(t * 1e9 + self.trace_offset_ns)

@@ -15,8 +15,10 @@ Departures, each as the configuration states it: a norm gain is stored as
 keys and values rounded to it, while prompt positions (prefilled in one
 pass) attend over them unrounded.
 
-The weights are read by name from the parameter tree the benchmark drew
-(``chipbench.weights``); nothing of the program is imported.
+The layer's parts, ``attention`` and ``swiglu``, are functions of their
+own, for a reference of mixed layers to compose.  The weights are read by
+name from the parameter tree the benchmark drew (``chipbench.weights``);
+nothing of the program is imported.
 """
 from __future__ import annotations
 
@@ -44,40 +46,50 @@ def _attend(q, k, v, precision):
     return mm("hqk,khd->qhd", p, v, precision)
 
 
-def hidden(params, dims, tokens, prompt_len, precision="highest"):
-    """Final normed hidden states (T, d) of one sequence.  ``tokens`` may be
-    padded at the end: attention is causal, so padding changes nothing
-    before it."""
-    lay = params["layers"]["seg0_dense"]
-    t = tokens.shape[0]
+def attention(a, h, dims, prompt_len, precision):
+    """Causal self-attention of one sequence's normed hidden states ``h``
+    (T, d) with the layer's weights ``a`` (``wq``, ``wk``, ``wv`` with
+    their biases, ``wo``): RoPE, grouped K/V heads repeated over the query
+    heads; positions from ``prompt_len`` on attend over K and V rounded to
+    ``kv_cache_dtype``."""
+    t = h.shape[0]
     hq, hkv, hd = dims.n_heads, dims.n_kv_heads, dims.head_dim
     pos = jnp.arange(t)
     decode_rows = (pos >= prompt_len)[:, None, None]
     kv_dt = jnp.dtype(dims.kv_cache_dtype)
+    q = (mm("td,de->te", h, a["wq"]["w"], precision) + a["wq"]["b"]).reshape(t, hq, hd)
+    k = (mm("td,de->te", h, a["wk"]["w"], precision) + a["wk"]["b"]).reshape(t, hkv, hd)
+    v = (mm("td,de->te", h, a["wv"]["w"], precision) + a["wv"]["b"]).reshape(t, hkv, hd)
+    q, k = _rope(q, pos, dims.rope_theta), _rope(k, pos, dims.rope_theta)
+    rep = hq // hkv
+    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    fresh = _attend(q, k, v, precision)
+    cached = _attend(q, k.astype(kv_dt).astype(jnp.float32),
+                     v.astype(kv_dt).astype(jnp.float32), precision)
+    o = jnp.where(decode_rows, cached, fresh).reshape(t, hq * hd)
+    return mm("te,ed->td", o, a["wo"]["w"], precision)
+
+
+def swiglu(m, h, precision):
+    """``(silu(h Wg) * (h Wu)) Wd`` with the layer's weights ``m``."""
+    g = mm("td,df->tf", h, m["gate"]["w"], precision)
+    u = mm("td,df->tf", h, m["up"]["w"], precision)
+    return mm("tf,fd->td", jax.nn.silu(g) * u, m["down"]["w"], precision)
+
+
+def hidden(params, dims, tokens, prompt_len, precision="highest"):
+    """Final normed hidden states (T, d) of one sequence.  ``tokens`` may be
+    padded at the end: attention is causal, so padding changes nothing
+    before it."""
 
     def layer(x, p):
-        a = p["attn"]
         h = rms_norm(x, p["norm1"], dims.norm_eps)
-        q = (mm("td,de->te", h, a["wq"]["w"], precision) + a["wq"]["b"]).reshape(t, hq, hd)
-        k = (mm("td,de->te", h, a["wk"]["w"], precision) + a["wk"]["b"]).reshape(t, hkv, hd)
-        v = (mm("td,de->te", h, a["wv"]["w"], precision) + a["wv"]["b"]).reshape(t, hkv, hd)
-        q, k = _rope(q, pos, dims.rope_theta), _rope(k, pos, dims.rope_theta)
-        rep = hq // hkv
-        k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-        fresh = _attend(q, k, v, precision)
-        cached = _attend(q, k.astype(kv_dt).astype(jnp.float32),
-                         v.astype(kv_dt).astype(jnp.float32), precision)
-        o = jnp.where(decode_rows, cached, fresh).reshape(t, hq * hd)
-        x = x + mm("te,ed->td", o, a["wo"]["w"], precision)
-        m = p["mlp"]
+        x = x + attention(p["attn"], h, dims, prompt_len, precision)
         h = rms_norm(x, p["norm2"], dims.norm_eps)
-        g = mm("td,df->tf", h, m["gate"]["w"], precision)
-        u = mm("td,df->tf", h, m["up"]["w"], precision)
-        x = x + mm("tf,fd->td", jax.nn.silu(g) * u, m["down"]["w"], precision)
-        return x, None
+        return x + swiglu(p["mlp"], h, precision), None
 
     x = params["embed"]["w"][tokens].astype(jnp.float32)
-    x, _ = jax.lax.scan(layer, x, lay)
+    x, _ = jax.lax.scan(layer, x, params["layers"]["seg0_dense"])
     return rms_norm(x, params["final_norm"], dims.norm_eps)
 
 

@@ -47,6 +47,8 @@ from chipbench import check, traffic  # noqa: E402
 from chipbench.client import drive, no_annotation  # noqa: E402
 from chipbench.peaks import peaks_for  # noqa: E402
 from chipbench.spec import arch_fields, load_cell  # noqa: E402
+from chipbench.weights import gemm_shapes  # noqa: E402
+from chipbench.workcount import Dims  # noqa: E402
 
 #: seconds of the window's end that a ``--trace 1`` run profiles
 TRACE_SECONDS = 10.0
@@ -200,21 +202,9 @@ def pct(values, q: float) -> float:
 
 
 def end_to_end(d, setup_s: float, seconds: float) -> tuple[dict, int, int]:
-    """The end-to-end metrics of a window, its attempted and failed
-    counts.  TTFT runs from when a request was due to its first token; a
-    request due in the window that got none by the end failed, and counts
-    in the tail at the time it had waited."""
-    from chipbench.traffic import WINDOW
-
-    due = [r for r in d.records.values()
-           if r.arrival.segment == WINDOW and r.due_t is not None]
-    ttft, failed = [], 0
-    for r in due:
-        if r.token_t:
-            ttft.append(r.token_t[0] - r.due_t)
-        else:
-            failed += 1
-            ttft.append(d.t_end - r.due_t)
+    """The client-side metrics of a window, its attempted and failed
+    counts; TTFT as ``Drive.ttft`` takes it."""
+    ttft, failed = d.ttft()
     gaps, emitted = [], 0
     for r in d.records.values():
         ts = r.token_t
@@ -225,7 +215,7 @@ def end_to_end(d, setup_s: float, seconds: float) -> tuple[dict, int, int]:
         out["ttft_p95_ms"] = (pct(ttft, 95) * 1e3, "ms")
     if gaps:
         out["itl_p95_ms"] = (pct(gaps, 95) * 1e3, "ms")
-    attempted = len(due) if due else sum(
+    attempted = len(ttft) if ttft else sum(
         1 for r in d.records.values() if r.submit_t is not None and r.submit_t < d.t_close)
     return out, attempted, failed
 
@@ -266,6 +256,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     counter = CompileCounter(jax)
     pauses = GcPauses()
     cfg, params, engine = build(cell, seed, trace)
+    dims = Dims.of(cfg, cell.config, engine.model.kinds, gemm_shapes(params))
     if breaker is not None:
         breaker(engine)
     vocab = int(cell.config["vocab_size"])
@@ -300,7 +291,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         from chipbench import readers
 
-        ctx = readers.Context.build(d, engine, cfg, prof, peaks_for(device.device_kind))
+        ctx = readers.Context.build(d, engine, dims, prof, peaks_for(device.device_kind))
         result["metrics"] = per_layer(cell, ctx)
         dev.update(busy_s=ctx.trace.busy_s, window_s=ctx.trace.window_s)
         result["breakdown"] = ctx.trace.breakdown()

@@ -61,6 +61,10 @@ ARCH_KEYS = {
     "rope_theta": "rope_theta",
     "rms_norm_eps": "norm_eps",
     "tie_word_embeddings": "tie_embeddings", "tie_embeddings": "tie_embeddings",
+    # Mamba-2 sizes as hybrid models publish them, flat
+    "mamba_d_state": "ssm_state", "mamba_d_head": "ssm_head_dim",
+    "mamba_expand": "ssm_expand", "mamba_d_conv": "ssm_conv",
+    "mamba_chunk_size": "ssm_chunk",
 }
 SSM_KEYS = {"d_state": "ssm_state", "headdim": "ssm_head_dim",
             "expand": "ssm_expand", "d_conv": "ssm_conv",

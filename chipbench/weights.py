@@ -13,7 +13,11 @@ leaf is drawn by a rule on its name:
   std 0.1;
 - Mamba-2 leaves as the paper initialises them: ``a_log`` = log U[1, 16],
   ``dt_bias`` = softplus^-1 of a log-uniform dt in [1e-3, 1e-1], ``d_skip``
-  near 1, the depthwise conv U[-1/2, 1/2].
+  near 1, the depthwise conv U[-1/2, 1/2];
+- a Mamba-2 conv bias, a leaf ``conv_b`` beside the conv's ``conv_w`` (K
+  taps over C channels, the taps on the second axis from the last):
+  U[-1/sqrt(K), 1/sqrt(K)], PyTorch's ``Conv1d`` default for a depthwise
+  conv of width K.
 """
 from __future__ import annotations
 
@@ -25,8 +29,9 @@ def _key_path(path) -> str:
     return "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
 
 
-def _draw(name: str, key, shape, dtype):
-    last = name.rsplit("/", 1)[-1]
+def _draw(name: str, key, shape, dtype, shapes: dict):
+    """Leaf ``name`` of the tree whose leaves have ``shapes`` (by name)."""
+    parent, _, last = name.rpartition("/")
     if name == "embed/w":
         return jax.random.normal(key, shape, dtype) * shape[-1] ** -0.5
     if last == "a_log":
@@ -38,11 +43,25 @@ def _draw(name: str, key, shape, dtype):
         return 1.0 + 0.1 * jax.random.normal(key, shape, dtype)
     if last == "conv_w":
         return jax.random.uniform(key, shape, dtype, -0.5, 0.5)
+    if last == "conv_b":
+        conv_w = f"{parent}/conv_w"
+        if conv_w not in shapes:
+            raise ValueError(f"conv bias {name!r} has no {conv_w!r} beside it")
+        bound = shapes[conv_w][-2] ** -0.5
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
     if last == "b" or "norm" in last:
         return 0.1 * jax.random.normal(key, shape, dtype)
     if last == "w":
         return jax.random.normal(key, shape, dtype) * shape[-2] ** -0.5
     raise ValueError(f"no weight rule for leaf {name!r}")
+
+
+def gemm_shapes(params) -> set[tuple[int, int]]:
+    """The (K, N) of every GEMM weight of the layers of a tree (or of its
+    shapes): its leaves ``w`` under ``layers``, stacked axes left off."""
+    leaves, _ = jax.tree_util.tree_flatten_with_path(params)
+    return {tuple(x.shape[-2:]) for p, x in leaves
+            if (n := _key_path(p)).startswith("layers/") and n.endswith("/w")}
 
 
 def root_key(seed: int):
@@ -56,9 +75,10 @@ def make_params(init_fn, seed: int):
     shapes = jax.eval_shape(init_fn, jax.random.key(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     names = [_key_path(p) for p, _ in leaves]
+    shapes_of = {n: s.shape for n, (_, s) in zip(names, leaves)}
 
     def build(key):
-        out = [_draw(n, jax.random.fold_in(key, i), s.shape, s.dtype)
+        out = [_draw(n, jax.random.fold_in(key, i), s.shape, s.dtype, shapes_of)
                for i, (n, (_, s)) in enumerate(zip(names, leaves))]
         return jax.tree_util.tree_unflatten(treedef, out)
 

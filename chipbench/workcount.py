@@ -10,14 +10,27 @@ roofline and utilization metric.
 So the shares read the same work whatever implements it, and no
 implementation on bf16 storage and the bf16 peak can push them past 100%.
 A change that moves GEMMs to the int8 rate needs the peak restated first.
+
+A model is a list of layers, each a tuple of components (``work/<name>.py``,
+found by name); a layer's work is the sum of its components', the model's
+the sum of its layers' and the vocabulary head once.  The list is the
+configuration file's ``layers``, one list of component names per layer::
+
+    "layers": [["ssm", "mlp"], ["ssm", "mlp"], ["attention", "mlp"]]
+
+or, without that key, the file's ``family`` (``FAMILY_LAYER``, the one
+layer it repeats).  ``Dims.of`` holds the list against the served model.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import importlib
 
 #: bf16 limbs each f32-ladder mode splits an operand into
 LIMBS = {"M8": 1, "M16": 2, "M24": 3}
-KV_BYTES = {"bfloat16": 2, "int8": 1, "float32": 4}
+#: the layer a family repeats, where the configuration file lists no layers
+FAMILY_LAYER = {"dense": ("attention", "mlp"), "ssm": ("ssm",)}
 
 
 def weight_bytes_per_elem(mode: str) -> int:
@@ -30,14 +43,40 @@ def gemm_flops(m: int, k: int, n: int) -> int:
     return 2 * m * k * n
 
 
+def component(name: str):
+    """The module ``chipbench.work.<name>`` that counts a component's work."""
+    if not name.isidentifier():
+        raise ValueError(f"no work count for component {name!r}: not a module name")
+    try:
+        return importlib.import_module(f"chipbench.work.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"chipbench.work.{name}":
+            raise
+        raise ValueError(f"no work count for component {name!r}: "
+                         f"no chipbench/work/{name}.py") from None
+
+
+def layer_list(config: dict, n_layers: int) -> tuple:
+    """Each layer's components, from a configuration file's ``layers`` or,
+    without it, its ``family`` (module docstring)."""
+    if "layers" in config:
+        return tuple(tuple(layer) for layer in config["layers"])
+    family = config.get("family")
+    if family not in FAMILY_LAYER:
+        raise ValueError(f"no layer list for family {family!r}: "
+                         "give the configuration file a 'layers' key")
+    return (FAMILY_LAYER[family],) * n_layers
+
+
 @dataclasses.dataclass(frozen=True)
 class Dims:
-    """The sizes the work depends on (the program's ArchConfig fields)."""
+    """The sizes the work depends on (the program's ArchConfig fields) and
+    the components of each layer (``layer_list``)."""
 
-    family: str
     n_layers: int
     d_model: int
     vocab: int
+    layers: tuple
     n_heads: int = 0
     n_kv_heads: int = 0
     head_dim: int = 0
@@ -48,34 +87,59 @@ class Dims:
     ssm_conv: int = 0
     kv_cache_dtype: str = "bfloat16"
 
+    def __post_init__(self):
+        if len(self.layers) != self.n_layers:
+            raise ValueError(f"{len(self.layers)} layers listed for a model of {self.n_layers}")
+        for name in {c for layer in self.layers for c in layer}:
+            component(name)
+
     @classmethod
-    def of(cls, cfg) -> "Dims":
-        return cls(**{f.name: getattr(cfg, f.name) for f in dataclasses.fields(cls)})
+    def of(cls, cfg, config: dict, kinds, gemm_shapes) -> "Dims":
+        """The widths of the program's ArchConfig ``cfg`` and the layers of
+        the cell's configuration file ``config``, held against the served
+        model: layers of one kind in the program's ``kinds`` (one per
+        layer) list the same components, layers of two kinds different
+        ones, and the layers' GEMMs have the (K, N) shapes of its weights
+        (``gemm_shapes``, ``chipbench.weights.gemm_shapes``)."""
+        fields = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cls)
+                  if f.name != "layers"}
+        d = cls(**fields, layers=layer_list(config, cfg.n_layers))
+        kinds = list(kinds)
+        pairs = set(zip(kinds, d.layers))
+        if len(kinds) != d.n_layers or not (
+                len(pairs) == len(set(kinds)) == len(set(d.layers))):
+            raise ValueError(f"the listed layers do not follow the program's kinds: "
+                             f"{sorted(pairs)} over {len(kinds)} layers")
+        listed = {s for layer in d.layers for name in layer
+                  for shapes in component(name).gemms(d).values() for s in shapes}
+        if listed != set(gemm_shapes):
+            raise ValueError(f"the layers' GEMMs {sorted(listed)} are not the served "
+                             f"model's weights {sorted(gemm_shapes)}")
+        return d
+
+    def _total(self, fn: str, *args) -> int:
+        """``fn`` of every component summed over the layers, 0 where a
+        component has no such function."""
+        counts = collections.Counter(c for layer in self.layers for c in layer)
+        total = 0
+        for name, n in counts.items():
+            f = getattr(component(name), fn, None)
+            if f is not None:
+                total += n * f(self, *args)
+        return total
 
     # -- weights per op class: (K, N) of each GEMM a token goes through ------
 
     def gemms(self) -> dict[str, list[tuple[int, int]]]:
         """Per op class, the (K, N) of every GEMM of one token's forward
         pass, all layers and the vocabulary head included."""
-        d, L = self.d_model, self.n_layers
-        if self.family == "dense":
-            q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
-            return {
-                "qkv": [(d, q), (d, kv), (d, kv)] * L,
-                "out": [(q, d)] * L,
-                "mlp_up": [(d, self.d_ff)] * 2 * L,
-                "mlp_down": [(self.d_ff, d)] * L,
-                "logits": [(d, self.vocab)],
-            }
-        if self.family == "ssm":
-            di = self.ssm_expand * d
-            h = di // self.ssm_head_dim
-            return {
-                "ssm_in": [(d, 2 * di + 2 * self.ssm_state + h)] * L,
-                "ssm_out": [(di, d)] * L,
-                "logits": [(d, self.vocab)],
-            }
-        raise ValueError(f"no work count for family {self.family!r}")
+        out: dict[str, list[tuple[int, int]]] = {}
+        for layer in self.layers:
+            for name in layer:
+                for op, shapes in component(name).gemms(self).items():
+                    out.setdefault(op, []).extend(shapes)
+        out.setdefault("logits", []).append((self.d_model, self.vocab))
+        return out
 
     def matmul_params(self) -> int:
         return sum(k * n for shapes in self.gemms().values() for k, n in shapes)
@@ -86,65 +150,37 @@ class Dims:
         return sum(k * n * weight_bytes_per_elem(mode_for(op))
                    for op, shapes in self.gemms().items() for k, n in shapes)
 
-    # -- per-token state --------------------------------------------------------
+    # -- per-row state ----------------------------------------------------------
 
     def kv_bytes_per_token(self) -> int:
         """KV cache bytes one cached token holds over all layers."""
-        return (2 * self.n_layers * self.n_kv_heads * self.head_dim
-                * KV_BYTES[self.kv_cache_dtype])
+        return self._total("token_bytes")
 
     def ssm_state_bytes(self) -> int:
-        """f32 SSM and conv state one sequence holds over all layers."""
-        di = self.ssm_expand * self.d_model
-        h = di // self.ssm_head_dim
-        ssm = h * self.ssm_head_dim * self.ssm_state
-        conv = (self.ssm_conv - 1) * (di + 2 * self.ssm_state)
-        return 4 * self.n_layers * (ssm + conv)
+        """State bytes one sequence holds over all layers, whatever its
+        length (the SSM state and conv window)."""
+        return self._total("state_bytes")
 
-    # -- flops ------------------------------------------------------------------
-
-    def attn_flops(self, q_tokens: int, context: int) -> int:
-        """Scores and weighted values of ``q_tokens`` queries against
-        ``context`` keys each, all layers (dense family)."""
-        if self.family != "dense":
-            return 0
-        return 2 * 2 * self.n_layers * self.n_heads * self.head_dim * q_tokens * context
-
-    def ssd_flops(self, tokens: int) -> int:
-        """State readout and update of the recurrent form, all layers: the
-        linear-time work per token (SSM family)."""
-        if self.family != "ssm":
-            return 0
-        di = self.ssm_expand * self.d_model
-        return 2 * 2 * self.n_layers * di * self.ssm_state * tokens
+    # -- one step ---------------------------------------------------------------
 
     def decode_flops(self, contexts: list[int]) -> int:
         """One decode token for each row, row ``i`` attending over
         ``contexts[i]`` cached tokens (its own included)."""
-        rows = len(contexts)
-        return (2 * rows * self.matmul_params()
-                + sum(self.attn_flops(1, c) for c in contexts)
-                + self.ssd_flops(rows))
+        return (2 * len(contexts) * self.matmul_params()
+                + sum(self._total("decode_flops", c) for c in contexts))
 
     def prefill_flops(self, prompt_len: int) -> int:
         """A causal forward pass over one prompt, with the logits of its
         last position only: the one a request needs."""
-        causal_pairs = prompt_len * (prompt_len + 1) // 2
         head = self.d_model * self.vocab
         return (2 * prompt_len * (self.matmul_params() - head) + 2 * head
-                + self.attn_flops(1, causal_pairs)
-                + self.ssd_flops(prompt_len))
+                + self._total("prefill_flops", prompt_len))
 
     def decode_bytes(self, contexts: list[int], mode_for) -> int:
-        """Least bytes of one decode step: the weights once, plus each live
-        row's cache read and its new entry written (dense), or its state
-        read and written (SSM)."""
-        b = self.weight_bytes(mode_for)
-        if self.family == "dense":
-            b += sum(c for c in contexts) * self.kv_bytes_per_token()
-        else:
-            b += 2 * len(contexts) * self.ssm_state_bytes()
-        return b
+        """Least bytes of one decode step: the weights once, plus what each
+        live row's components read and write (its cache, its state)."""
+        return self.weight_bytes(mode_for) + sum(self._total("decode_bytes", c)
+                                                 for c in contexts)
 
 
 def least_seconds(flops: float, nbytes: float, peaks) -> tuple[float, str]:

@@ -3,6 +3,7 @@ control a step below in precision reading clearly worse."""
 from __future__ import annotations
 
 import dataclasses
+import types
 
 import jax.numpy as jnp
 import numpy as np
@@ -62,3 +63,68 @@ def test_control_reads_far_worse_than_reference(family, control):
     err = np.abs(low - exact).max() / np.abs(exact).max()
     floor = {"high": 1e-6, "bf16": 1e-3, "fp8": 1e-2}[control]
     assert err > floor
+
+
+def test_ssm_reference_adds_a_conv_bias_where_the_tree_has_one():
+    """A bias of zero reads as no bias at all, and a drawn one moves the
+    logits: the reference reads the ``conv_b`` leaf."""
+    cfg, _, params = program("ssm")
+    toks = np.random.default_rng(2).integers(0, 200, 24).astype(np.int32)
+    ref = check.Reference("ssm", check.ref_dims(cfg), 48)
+    rows = np.arange(24)
+
+    def logits(conv_b):
+        p = params
+        if conv_b is not None:
+            lay = dict(p["layers"]["seg0_ssm"], ssm=dict(p["layers"]["seg0_ssm"]["ssm"],
+                                                         conv_b=conv_b))
+            p = dict(p, layers={"seg0_ssm": lay})
+        return np.asarray(ref.logits(p, ref.hidden(p, toks, 24), rows))
+
+    shape = params["layers"]["seg0_ssm"]["ssm"]["conv_w"].shape
+    bias_shape = shape[:1] + shape[2:]
+    none = logits(None)
+    np.testing.assert_array_equal(logits(jnp.zeros(bias_shape, jnp.float32)), none)
+    drawn = np.random.default_rng(3).uniform(-0.5, 0.5, bias_shape).astype(np.float32)
+    assert np.abs(logits(jnp.asarray(drawn)) - none).max() > 1e-2 * np.abs(none).max()
+
+
+def test_attention_by_hand():
+    """The dense reference's attention, as a reference of mixed layers
+    composes it, against numpy: biased q/k/v projections, rotate-half RoPE,
+    grouped heads, causal softmax of q k^T / sqrt(hd), the output
+    projection.  A tree without the q/k/v biases is refused."""
+    from chipbench.reference.dense import attention
+
+    rng = np.random.default_rng(4)
+    t, d, hq, hkv, hd, theta = 5, 8, 4, 2, 4, 10000.0
+    w = {n: rng.normal(size=(d, e)).astype(np.float32)
+         for n, e in (("wq", hq * hd), ("wk", hkv * hd), ("wv", hkv * hd))}
+    b = {n: rng.normal(size=(x.shape[1],)).astype(np.float32) for n, x in w.items()}
+    wo = rng.normal(size=(hq * hd, d)).astype(np.float32)
+    h = rng.normal(size=(t, d)).astype(np.float32)
+    dims = types.SimpleNamespace(n_heads=hq, n_kv_heads=hkv, head_dim=hd,
+                                 kv_cache_dtype="bfloat16", rope_theta=theta)
+    a = {n: {"w": jnp.asarray(w[n]), "b": jnp.asarray(b[n])} for n in w}
+    a["wo"] = {"w": jnp.asarray(wo)}
+    got = np.asarray(attention(a, jnp.asarray(h), dims, t, "highest"))
+
+    def rope(x):
+        half = hd // 2
+        ang = np.arange(t)[:, None] * theta ** (-np.arange(half) / half)
+        cos, sin = np.cos(ang)[:, None], np.sin(ang)[:, None]
+        x1, x2 = x[..., :half], x[..., half:]
+        return np.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+    q, k, v = ((h.astype(np.float64) @ w[n] + b[n]).reshape(t, -1, hd)
+               for n in ("wq", "wk", "wv"))
+    q, k = rope(q), rope(k)
+    k, v = np.repeat(k, hq // hkv, axis=1), np.repeat(v, hq // hkv, axis=1)
+    s = np.einsum("qhd,khd->hqk", q, k) / np.sqrt(hd)
+    s = s + np.where(np.tri(t, dtype=bool), 0, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    o = np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True), v).reshape(t, hq * hd)
+    np.testing.assert_allclose(got, o @ wo, rtol=1e-5, atol=1e-5)
+    a["wk"] = {"w": a["wk"]["w"]}
+    with pytest.raises(KeyError):
+        attention(a, jnp.asarray(h), dims, t, "highest")

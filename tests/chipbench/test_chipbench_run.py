@@ -110,3 +110,32 @@ def test_cpu_has_no_peaks():
         run.require_chips(type("J", (), {"devices": staticmethod(
             lambda: [type("D", (), {"platform": "tpu", "device_kind": "cpu"})()])}), 1)
     assert jax.devices()[0].platform == "cpu"
+
+
+def test_ttft_is_read_alike_end_to_end_and_per_layer():
+    """The client's TTFT tail: every request due in the window, one with no
+    first token counted at the time it waited; the per-layer reader and the
+    end-to-end reduction read the same number."""
+    import types
+
+    from chipbench.client import Drive, Record
+    from chipbench.metrics import ttft_p95_ms
+    from chipbench.traffic import TAIL, WINDOW, Arrival
+
+    recs = {}
+    for i in range(19):
+        r = Record(Arrival(i, float(i), 384, 4, WINDOW), due_t=10.0 + i)
+        r.token_t = [10.0 + i + 0.1 * (i + 1), 12.0 + i]
+        recs[i] = r
+    recs[19] = Record(Arrival(19, 19.0, 384, 4, WINDOW), due_t=29.0)
+    recs[20] = Record(Arrival(20, 60.0, 384, 4, TAIL), due_t=70.0)
+    recs[20].token_t = [90.0]
+    d = Drive(recs, [], t_open=10.0, t_close=40.0, t_end=33.0)
+    waits, failed = d.ttft()
+    assert failed == 1 and len(waits) == 20
+    assert max(waits) == pytest.approx(4.0)
+    out, attempted, failed = run.end_to_end(d, 1.0, 30.0)
+    per_layer = ttft_p95_ms.read(types.SimpleNamespace(drive=d), "itl")
+    assert attempted == 20 and failed == 1
+    assert per_layer == out["ttft_p95_ms"][0]
+    assert per_layer == pytest.approx(1e3 * (1.9 + 0.05 * (4.0 - 1.9)))
