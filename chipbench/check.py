@@ -39,11 +39,14 @@ def reference_module(family: str):
     return importlib.import_module(f"chipbench.reference.{family}")
 
 
-def ref_dims(arch_cfg) -> types.SimpleNamespace:
-    """The sizes a reference reads, copied from the configuration as run."""
+def ref_dims(arch_cfg, config: dict) -> types.SimpleNamespace:
+    """The sizes a reference reads, copied from the configuration as run
+    (the program's ArchConfig ``arch_cfg``), and under ``published`` the
+    cell's configuration file ``config`` as loaded: the source's constants
+    by its own keys (``chipbench.reference.common``)."""
     fields = {f.name: getattr(arch_cfg, f.name) for f in dataclasses.fields(arch_cfg)
               if f.name != "policy"}
-    return types.SimpleNamespace(**fields)
+    return types.SimpleNamespace(**fields, published=config)
 
 
 def sample(records: dict, seed: int, tokens: int, max_requests: int) -> list:

@@ -7,6 +7,17 @@ a configuration's own: ``high`` (three bf16 passes: hi*hi + hi*lo + lo*hi),
 ``bf16`` (one pass) and ``fp8`` (operands rounded to float8_e4m3fn).  The
 controls split and round their operands explicitly and sum exact products
 in f32, so they read the same on any backend.
+
+A reference is ``reference/<family>.py``, found by the configuration
+file's ``family``, with ``hidden(params, dims, tokens, prompt_len,
+precision)`` and ``head(params, dims)``.  ``dims`` (``check.ref_dims``)
+holds the program's ArchConfig fields, which ``spec.ARCH_KEYS`` maps from
+the configuration file, and the file itself, as loaded, under
+``dims.published``.  A reference takes a source constant that the
+ArchConfig does not map through ``spec.ARCH_KEYS`` (a multiplier, a
+positional encoding) from ``dims.published``, by the source's own key,
+never from a field of the program: an error in the program's registry must
+read as a difference, not agree with itself.
 """
 from __future__ import annotations
 

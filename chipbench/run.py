@@ -322,7 +322,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         for r in sampled]
     del engine
     gc.collect()
-    ref = check.Reference(cell.config["family"], check.ref_dims(cfg),
+    ref = check.Reference(cell.config["family"], check.ref_dims(cfg, cell.config),
                           int(cell.config["serving"]["max_len"]))
     values = check.readings(ref, params, sampled, prefill_logits, control=control)
     ok, compared = check.verdict(values, lim)

@@ -56,12 +56,19 @@ def _draw(name: str, key, shape, dtype, shapes: dict):
     raise ValueError(f"no weight rule for leaf {name!r}")
 
 
+#: the ``w`` leaves that are no layer's GEMM: the embedding table and the
+#: vocabulary head, which the work count adds once
+NOT_LAYER_GEMMS = ("embed/w", "unembed/w")
+
+
 def gemm_shapes(params) -> set[tuple[int, int]]:
     """The (K, N) of every GEMM weight of the layers of a tree (or of its
-    shapes): its leaves ``w`` under ``layers``, stacked axes left off."""
+    shapes), wherever the tree keeps its layers (``layers/``, a hybrid's
+    ``super/`` and ``rem/``): every leaf named ``w`` but the embedding and
+    the vocabulary head, stacked axes left off."""
     leaves, _ = jax.tree_util.tree_flatten_with_path(params)
     return {tuple(x.shape[-2:]) for p, x in leaves
-            if (n := _key_path(p)).startswith("layers/") and n.endswith("/w")}
+            if (n := _key_path(p)).endswith("/w") and n not in NOT_LAYER_GEMMS}
 
 
 def root_key(seed: int):

@@ -100,7 +100,9 @@ class Dims:
         model: layers of one kind in the program's ``kinds`` (one per
         layer) list the same components, layers of two kinds different
         ones, and the layers' GEMMs have the (K, N) shapes of its weights
-        (``gemm_shapes``, ``chipbench.weights.gemm_shapes``)."""
+        (``gemm_shapes``, ``chipbench.weights.gemm_shapes``: every ``w``
+        leaf of its parameter tree but the embedding and the vocabulary
+        head, wherever the tree keeps its layers)."""
         fields = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cls)
                   if f.name != "layers"}
         d = cls(**fields, layers=layer_list(config, cfg.n_layers))

@@ -9,6 +9,7 @@ import pytest
 
 import chipbench_smoke as smoke
 from chipbench.spec import arch_fields, load_json
+from chipbench.workcount import layer_list
 
 BENCH = json.loads((smoke.ROOT / "BENCHMARK.json").read_text())
 
@@ -33,22 +34,29 @@ def test_mamba2_keeps_the_source_ssm_cfg_and_runs_the_layer_defaults():
     assert fields["d_model"] == 2560 and fields["vocab"] == 50288
 
 
-#: ``arch_fields`` of each configuration file as the harness read it before
-#: it read flat Mamba-2 keys
-ARCH_FIELDS = {
-    "qwen1.5-0.5b": {"vocab": 151936, "d_model": 1024, "n_layers": 24, "n_heads": 16,
-                     "n_kv_heads": 16, "d_ff": 2816, "rope_theta": 1000000.0,
-                     "norm_eps": 1e-06, "tie_embeddings": False,
-                     "kv_cache_dtype": "bfloat16"},
-    "mamba2-2.7b": {"vocab": 50288, "d_model": 2560, "n_layers": 32, "tie_embeddings": True,
-                    "ssm_state": 128, "ssm_head_dim": 64, "ssm_expand": 2, "ssm_conv": 4,
-                    "ssm_chunk": 256},
-}
+DATA = smoke.ROOT / "tests" / "chipbench" / "data"
+
+
+def pinned_arch_fields(name: str) -> dict:
+    """``arch_fields`` of configuration ``name`` as the harness read it
+    before it read flat Mamba-2 keys: ``data/arch-fields-<name>.json``,
+    which a new configuration brings."""
+    path = DATA / f"arch-fields-{name}.json"
+    if not path.is_file():
+        pytest.fail(f"no pinned arch_fields for configuration {name!r}: "
+                    f"add {path.relative_to(smoke.ROOT)}")
+    return load_json(path)
 
 
 @pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
 def test_arch_fields_of_the_configuration_files_stay(conf):
-    assert arch_fields(load_json(smoke.ROOT / conf["file"])) == ARCH_FIELDS[conf["name"]]
+    assert arch_fields(load_json(smoke.ROOT / conf["file"])) == pinned_arch_fields(conf["name"])
+
+
+def test_a_missing_pin_names_the_file_to_add():
+    with pytest.raises(pytest.fail.Exception,
+                       match="tests/chipbench/data/arch-fields-no-such-config.json"):
+        pinned_arch_fields("no-such-config")
 
 
 def test_flat_mamba2_keys_give_the_ssm_fields():
@@ -62,3 +70,68 @@ def test_flat_mamba2_keys_give_the_ssm_fields():
     assert fields == {"vocab": 100352, "d_model": 2048, "n_layers": 20, "ssm_state": 128,
                       "ssm_head_dim": 64, "ssm_expand": 2, "ssm_conv": 4, "ssm_chunk": 256}
     assert set(fields) <= {f.name for f in dataclasses.fields(ArchConfig)}
+
+
+# -- what a new entry of BENCHMARK.json brings ------------------------------------------
+
+
+def configuration_needs(entry: dict, conf: dict | None) -> list[str]:
+    """The files, relative to the repository's root, that a configuration
+    entry needs beside it, given its file's content ``conf`` (None where it
+    has none yet)."""
+    out = [entry["file"], f"tests/chipbench/data/arch-fields-{entry['name']}.json"]
+    if conf is None:
+        return out
+    family = conf.get("family")
+    out += [f"chipbench/reference/{family}.py", f"tests/chipbench/smoke/{family}.json"]
+    try:
+        layers = layer_list(conf, 1)
+    except ValueError as e:
+        return out + [f"a 'layers' key in {entry['file']} ({e})"]
+    return out + [f"chipbench/work/{c}.py" for c in sorted({c for lay in layers for c in lay})]
+
+
+def cell_needs(entry: dict) -> list[str]:
+    return [f"chipbench/mixes/{entry['traffic']}.json",
+            f"chipbench/limits/{entry['name']}.json",
+            f"tests/chipbench/data/limits-{entry['name']}.jsonl"]
+
+
+def missing(needed: list[str]) -> list[str]:
+    return [f for f in needed if not (smoke.ROOT / f).is_file()]
+
+
+def needs(kind: str, entry: dict) -> list[str]:
+    if kind == "cell":
+        return cell_needs(entry)
+    path = smoke.ROOT / entry["file"]
+    return configuration_needs(entry, load_json(path) if path.is_file() else None)
+
+
+ENTRIES = ([pytest.param("configuration", c, id=f"configuration-{c['name']}")
+            for c in BENCH["configs"]]
+           + [pytest.param("cell", w, id=f"cell-{w['name']}") for w in BENCH["workloads"]])
+
+
+@pytest.mark.parametrize("kind,entry", ENTRIES)
+def test_an_entry_brings_every_file_it_needs(kind, entry):
+    """A configuration brings its file, a reference of its family, a smoke
+    configuration of that family, its pinned ``arch_fields`` and a work
+    count of every component its layers name; a cell its mix, its limits
+    and the readings they were set from.  Each is a new file, found by
+    name: no file of the harness is edited for a new entry."""
+    lacking = missing(needs(kind, entry))
+    assert not lacking, f"the {kind} {entry['name']!r} needs: {', '.join(lacking)}"
+
+
+def test_a_new_family_is_told_each_file_it_lacks():
+    entry = {"name": "x", "file": "chipbench/configs/x.json"}
+    conf = {"family": "hybrid", "layers": [["ssm", "mlp"], ["attention", "mlp"], ["moe"]]}
+    assert missing(configuration_needs(entry, conf)) == [
+        "chipbench/configs/x.json", "tests/chipbench/data/arch-fields-x.json",
+        "chipbench/reference/hybrid.py", "tests/chipbench/smoke/hybrid.json",
+        "chipbench/work/moe.py"]
+    lacking = missing(configuration_needs(entry, {"family": "hybrid"}))
+    assert lacking[-1].startswith("a 'layers' key in chipbench/configs/x.json")
+    assert missing(cell_needs({"name": "y", "traffic": "chat-f32"})) == [
+        "chipbench/limits/y.json", "tests/chipbench/data/limits-y.jsonl"]

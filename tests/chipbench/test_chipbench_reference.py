@@ -20,13 +20,17 @@ def program(family):
     from repro.core.policy import NATIVE_F32
     from repro.models import build_model
 
-    conf = smoke.DENSE if family == "dense" else smoke.SSM
+    conf = smoke.config(family)
     cfg = dataclasses.replace(get_config(conf["arch"]), **arch_fields(conf))
     model = build_model(cfg.with_policy(NATIVE_F32))
     return cfg, model, make_params(model.init, 11)
 
 
-@pytest.mark.parametrize("family", ["dense", "ssm"])
+def reference(family, cfg):
+    return check.Reference(family, check.ref_dims(cfg, smoke.config(family)), 48)
+
+
+@pytest.mark.parametrize("family", smoke.FAMILIES)
 def test_reference_matches_program_prefill_and_decode(family):
     cfg, model, params = program(family)
     prompt, extra = 16, 5
@@ -38,7 +42,7 @@ def test_reference_matches_program_prefill_and_decode(family):
         lg, state = model.decode_step(params, jnp.asarray([[t]]), state)
         prog.append(np.asarray(lg[0]))
     prog = np.concatenate(prog)
-    ref = check.Reference(family, check.ref_dims(cfg), 48)
+    ref = reference(family, cfg)
     h = ref.hidden(params, toks, prompt)
     want = ref.logits(params, h, np.arange(prompt + extra))
     err = np.abs(prog - want).max(axis=1) / np.abs(want).max()
@@ -56,7 +60,7 @@ def test_reference_matches_program_prefill_and_decode(family):
 def test_control_reads_far_worse_than_reference(family, control):
     cfg, model, params = program(family)
     toks = np.random.default_rng(1).integers(0, 200, 24).astype(np.int32)
-    ref = check.Reference(family, check.ref_dims(cfg), 48)
+    ref = reference(family, cfg)
     rows = np.arange(24)
     exact = ref.logits(params, ref.hidden(params, toks, 24), rows)
     low = ref.logits(params, ref.hidden(params, toks, 24, control), rows, control)
@@ -65,12 +69,31 @@ def test_control_reads_far_worse_than_reference(family, control):
     assert err > floor
 
 
+@pytest.mark.parametrize("family", ["dense", "ssm"])
+def test_references_carry_the_published_file_and_ignore_it(family):
+    """``dims.published`` is the cell's configuration file as loaded; the
+    dense and SSM references take every size from the program's ArchConfig
+    fields and read the same logits without it."""
+    cfg, _, params = program(family)
+    conf = smoke.config(family)
+    dims = check.ref_dims(cfg, conf)
+    assert dims.published is conf
+    toks = np.random.default_rng(5).integers(0, 200, 24).astype(np.int32)
+    rows = np.arange(24)
+    bare = types.SimpleNamespace(**{k: v for k, v in vars(dims).items() if k != "published"})
+    got = {}
+    for name, d in (("published", dims), ("bare", bare)):
+        ref = check.Reference(family, d, 48)
+        got[name] = np.asarray(ref.logits(params, ref.hidden(params, toks, 16), rows))
+    np.testing.assert_array_equal(got["published"], got["bare"])
+
+
 def test_ssm_reference_adds_a_conv_bias_where_the_tree_has_one():
     """A bias of zero reads as no bias at all, and a drawn one moves the
     logits: the reference reads the ``conv_b`` leaf."""
     cfg, _, params = program("ssm")
     toks = np.random.default_rng(2).integers(0, 200, 24).astype(np.int32)
-    ref = check.Reference("ssm", check.ref_dims(cfg), 48)
+    ref = reference("ssm", cfg)
     rows = np.arange(24)
 
     def logits(conv_b):

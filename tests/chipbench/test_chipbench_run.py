@@ -57,7 +57,7 @@ def half_batch(engine):
     engine._step = broken
 
 
-@pytest.mark.parametrize("family", ["dense", "ssm"])
+@pytest.mark.parametrize("family", smoke.FAMILIES)
 def test_sound_run_is_correct(family):
     r = result(family)
     assert r["correct"], r["compared"]
@@ -82,10 +82,17 @@ def test_cells_control_in_the_programs_place_is_not_correct(name):
 
 
 @pytest.mark.parametrize("fault", [altered_token, state_unchanged, half_batch])
-@pytest.mark.parametrize("family", ["dense", "ssm"])
+@pytest.mark.parametrize("family", smoke.FAMILIES)
 def test_fault_in_timed_path_is_not_correct(family, fault):
     r = result(family, breaker=fault)
     assert not r["correct"], r["compared"]
+
+
+def test_a_family_with_no_smoke_configuration_is_refused():
+    """No fallback to another family's smoke cell: a new family's tests run
+    on its own smoke configuration, or fail and name the file to add."""
+    with pytest.raises(FileNotFoundError, match="tests/chipbench/smoke/no-such-family.json"):
+        smoke.cell("no-such-family")
 
 
 def test_end_to_end_metrics_of_a_window():

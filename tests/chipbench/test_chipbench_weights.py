@@ -98,3 +98,17 @@ def test_gemm_shapes_are_the_layers_projection_weights():
     shapes = {f: tree_of({n: s for n, (s, _) in DRAWN[f].items()}) for f in DRAWN}
     assert gemm_shapes(shapes["dense"]) == {(64, 64), (64, 128), (128, 64)}
     assert gemm_shapes(shapes["ssm"]) == {(64, 296), (128, 64)}
+
+
+def test_gemm_shapes_read_a_hybrid_tree():
+    """A hybrid keeps its layers under ``super/`` (stacked over the pattern's
+    repeats) and ``rem/`` (one each): their ``w`` leaves are the layers'
+    GEMMs; the embedding and the vocabulary head are not, whatever their
+    shapes."""
+    tree = tree_of({
+        "embed/w": (512, 64), "unembed/w": (64, 512), "final_norm": (64,),
+        "super/l0_ssm/ssm/in_proj/w": (2, 64, 296), "super/l0_ssm/ssm/conv_w": (2, 4, 160),
+        "super/l0_ssm/mlp/down/w": (2, 128, 64), "super/l1_attention/attn/wq/w": (2, 64, 32),
+        "rem/l0_ssm/ssm/out_proj/w": (128, 48), "rem/l0_ssm/norm1": (64,),
+    })
+    assert gemm_shapes(tree) == {(64, 296), (128, 64), (64, 32), (128, 48)}
